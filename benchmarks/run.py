@@ -1277,6 +1277,8 @@ def main() -> None:
                          "request-lifecycle timelines)")
     args = ap.parse_args()
     only = args.only or args.scenario
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for b in BENCHES:
         if only and only not in b.__name__:

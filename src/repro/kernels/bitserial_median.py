@@ -7,14 +7,14 @@ RRAM arrays computing the majority vote in place instead of streaming the
 operands to the core B times.
 
 Layout (per grid instance):
-  u      (N, TD)  uint32  — unsigned-ordered fixed-point data, full point
+  u      (N, TD)  int32   — unsigned-ordered fixed-point bits, full point
                             axis resident (the paper's "limited-size array";
                             the VMEM capacity plays the role of the array
                             size limit; ops.py falls back to the two-level
                             reduction-tree path above the VMEM limit)
   assign (N, 1)   int32   — cluster ids (the paper's P/I inclusion predicate)
   w      (N, 1)   f32     — per-point weights (mask / merge counts)
-  med    (K, TD)  uint32  — per-cluster medians (output)
+  med    (K, TD)  int32   — per-cluster median bits (output)
 
 Grid: (D // TD,).  K is a compile-time constant.  Per bit the vote count is
 a one-hot matmul (MXU): cnt1[k, d] = Σ_i onehot[i, k] · eff[i, d]; the
@@ -33,7 +33,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(u_ref, assign_ref, w_ref, med_ref, *, k: int, bits: int):
-    u = u_ref[...]                      # (N, TD) uint32
+    u = u_ref[...]                      # (N, TD) int32 bit pattern
     assign = assign_ref[...]            # (N, 1) int32
     w = w_ref[...]                      # (N, 1) f32
     n = u.shape[0]
@@ -45,23 +45,22 @@ def _kernel(u_ref, assign_ref, w_ref, med_ref, *, k: int, bits: int):
 
     active0 = jnp.ones(u.shape, jnp.float32)
     forced0 = jnp.zeros(u.shape, jnp.float32)
-    med0 = jnp.zeros(med_ref.shape, jnp.uint32)
+    med0 = jnp.zeros(med_ref.shape, jnp.int32)
 
     def body(i, carry):
         active, forced, med = carry
-        b = (jnp.uint32(bits - 1) - i.astype(jnp.uint32))
-        bit = (jax.lax.shift_right_logical(u, b) & jnp.uint32(1)
-               ).astype(jnp.float32)                            # (N, TD)
+        b = jnp.int32(bits - 1) - i
+        # logical shift of the int32 bit pattern == the uint32 scan; the
+        # 0/1 plane goes through int32 (Mosaic has no uint32 → f32 cast)
+        bit = (jax.lax.shift_right_logical(u, b) & 1).astype(jnp.float32)
         eff = active * bit + (1.0 - active) * forced
         # vote count: (K, N) x (N, TD) on the MXU
         cnt1 = jax.lax.dot_general(
             onehot, eff, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                  # (K, TD)
         mbit = (cnt1 * 2.0 > total[:, None]).astype(jnp.float32)  # (K, TD)
-        med = med | jnp.where(
-            mbit > 0.5,
-            jax.lax.shift_left(jnp.uint32(1), b),
-            jnp.uint32(0))
+        med = med | jnp.where(mbit > 0.5, jax.lax.shift_left(jnp.int32(1), b),
+                              jnp.int32(0))
         # broadcast decision back to points: (N, K) x (K, TD)
         mper = jax.lax.dot_general(
             onehot01, mbit, (((1,), (0,)), ((), ())),
@@ -81,12 +80,15 @@ def grouped_median_pallas(u, assign, weights, k: int, *, bits: int = 32,
 
     The full point axis is VMEM-resident; the grid tiles D only.  Callers
     above the VMEM budget use the two-level reduction-tree path in ops.py.
+    The kernel scans the int32 view of ``u``'s bits, which is bitwise the
+    uint32 scan.
     """
     n, d = u.shape
     pad_d = (-d) % d_block
     if pad_d:
         u = jnp.pad(u, ((0, 0), (0, pad_d)))
     dp = d + pad_d
+    u32 = jax.lax.bitcast_convert_type(u.astype(jnp.uint32), jnp.int32)
     assign2 = assign.reshape(n, 1).astype(jnp.int32)
     w2 = weights.reshape(n, 1).astype(jnp.float32)
 
@@ -102,7 +104,7 @@ def grouped_median_pallas(u, assign, weights, k: int, *, bits: int = 32,
         ],
         out_specs=pl.BlockSpec((k, d_block), lambda j: (0, j),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((k, dp), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((k, dp), jnp.int32),
         interpret=interpret,
-    )(u, assign2, w2)
-    return out[:, :d]
+    )(u32, assign2, w2)
+    return jax.lax.bitcast_convert_type(out[:, :d], jnp.uint32)

@@ -26,14 +26,19 @@ t+i-R, so ``cov >= t + chunk_len - R`` must hold (the engine's
 absorb_chunk pre-pass guarantees it) — the overwritten positions are
 then summarized by centroids and nothing is lost.
 
-Layout (grid = (B, Hkv)):
-  t, cov, chunk_len  (1,)  SMEM  — slot valid length / coverage / rows
-  q        (1, 1, L, G, Dh)  VMEM  — this kv-head's query rows
-  k_cents  (1, C, 1, Dh)     VMEM     v_cents same
-  counts   (1, 1, C)         VMEM  — pre-transposed (B, Hkv, C)
-  k_tail   (1, R, 1, Dh)     VMEM     v_tail same (ring order, chunk
-                                      rows already written)
-  out      (1, 1, L, G, Dh)
+Layout (grid = (B, Hkv); scalar prefetch: t, cov, chunk_len (B,) in
+SMEM, read at ``pl.program_id(0)``).  Head-indexed operands are viewed
+with heads folded into the lane axis, so every block's last two dims are
+either full or (8, 128)-aligned as Mosaic requires:
+  q        (1, 1, L*G, Dh)  VMEM  — this kv-head's query rows, (B, Hkv,
+                                    L*G, Dh)
+  k_cents  (1, C, Dh)       VMEM  — lane block h of (B, C, Hkv*Dh);
+                                    v_cents same
+  counts   (1, 1, 1, C)     VMEM  — (B, Hkv, 1, C)
+  k_tail   (1, R, Dh)       VMEM  — lane block h of (B, R, Hkv*Dh) (ring
+                                    order, chunk rows already written);
+                                    v_tail same
+  out      (1, 1, L*G, Dh)
 """
 
 from __future__ import annotations
@@ -45,21 +50,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5: experimental namespace
-    from jax.experimental.shard_map import shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma when
-# shard_map moved to the top-level namespace; resolve it by signature so
-# both APIs disable the check (the Pallas call has no replication rule)
-import inspect as _inspect
-
-_SHARD_MAP_NO_CHECK = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(shard_map).parameters
-    else {"check_rep": False})
 
 NEG = -1e30
 
@@ -106,15 +96,16 @@ def score_and_combine(q, kc, vc, cnt, kt, vt, row_ok, tail_ok, *,
 
 def _kernel(t_ref, cov_ref, len_ref, q_ref, kc_ref, vc_ref, cnt_ref, kt_ref,
             vt_ref, o_ref, *, l: int, g: int, r: int, scale: float, softcap):
-    t = t_ref[0]
-    cov = cov_ref[0]
-    cl = len_ref[0]
-    q = q_ref[0, 0].astype(jnp.float32).reshape(l * g, -1)   # (L*G, Dh)
-    kc = kc_ref[0, :, 0].astype(jnp.float32)                 # (C, Dh)
-    vc = vc_ref[0, :, 0].astype(jnp.float32)
-    cnt = cnt_ref[0, 0].astype(jnp.float32)                  # (C,)
-    kt = kt_ref[0, :, 0].astype(jnp.float32)                 # (R, Dh)
-    vt = vt_ref[0, :, 0].astype(jnp.float32)
+    i = pl.program_id(0)
+    t = t_ref[i]
+    cov = cov_ref[i]
+    cl = len_ref[i]
+    q = q_ref[0, 0].astype(jnp.float32)                      # (L*G, Dh)
+    kc = kc_ref[0].astype(jnp.float32)                       # (C, Dh)
+    vc = vc_ref[0].astype(jnp.float32)
+    cnt = cnt_ref[0, 0, 0].astype(jnp.float32)               # (C,)
+    kt = kt_ref[0].astype(jnp.float32)                       # (R, Dh)
+    vt = vt_ref[0].astype(jnp.float32)
 
     # query row i*g + j carries chunk index i → absolute position t + i
     li = jax.lax.broadcasted_iota(jnp.int32, (l * g, 1), 0) // g
@@ -131,7 +122,7 @@ def _kernel(t_ref, cov_ref, len_ref, q_ref, kc_ref, vc_ref, cnt_ref, kt_ref,
 
     out = score_and_combine(q, kc, vc, cnt, kt, vt, row_ok, ok,
                             scale=scale, softcap=softcap)
-    o_ref[0, 0] = out.reshape(l, g, -1).astype(o_ref.dtype)
+    o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def clustered_decode_shardmap(q, k_cents, v_cents, counts, k_tail, v_tail,
@@ -159,7 +150,7 @@ def clustered_decode_shardmap(q, k_cents, v_cents, counts, k_tail, v_tail,
     qspec = P(data_axes, model_axes, None) if q.ndim == 3 else \
         P(data_axes, None, model_axes, None)
     d, m = data_axes, model_axes
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(clustered_decode_pallas, scale=scale,
                           softcap=softcap, interpret=interpret),
         mesh=mesh,
@@ -175,7 +166,7 @@ def clustered_decode_shardmap(q, k_cents, v_cents, counts, k_tail, v_tail,
             P(d),                 # chunk_len (B,)
         ),
         out_specs=qspec,
-        **_SHARD_MAP_NO_CHECK,
+        check_vma=False,  # the Pallas call has no replication rule
     )
     return f(q, k_cents, v_cents, counts, k_tail, v_tail, t, cov, chunk_len)
 
@@ -200,39 +191,45 @@ def clustered_decode_pallas(q, k_cents, v_cents, counts, k_tail, v_tail,
     r = k_tail.shape[1]
     hkv = k_cents.shape[2]
     g = hq // hkv
-    qh = q.reshape(b, l, hkv, g, dh).transpose(0, 2, 1, 3, 4)
-    cnt_t = counts.transpose(0, 2, 1)                    # (B, Hkv, C)
+    qh = q.reshape(b, l, hkv, g, dh).transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, l * g, dh)
+    cnt_t = counts.transpose(0, 2, 1).reshape(b, hkv, 1, c)  # (B, Hkv, 1, C)
     t = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (b,))
     cov = jnp.broadcast_to(jnp.asarray(cov, jnp.int32), (b,))
     if chunk_len is None:
         chunk_len = jnp.ones((b,), jnp.int32)
     chunk_len = jnp.broadcast_to(jnp.asarray(chunk_len, jnp.int32), (b,))
 
+    def lanes(x):                       # (B, n, Hkv, Dh) → (B, n, Hkv*Dh)
+        return x.reshape(x.shape[0], x.shape[1], hkv * dh)
+
+    head_block = lambda n: pl.BlockSpec(                     # noqa: E731
+        (1, n, dh), lambda i, h, *_: (i, 0, h))
+    rows_block = pl.BlockSpec((1, 1, l * g, dh),
+                              lambda i, h, *_: (i, h, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,                # t, cov, chunk_len
+        grid=(b, hkv),
+        in_specs=[
+            rows_block,
+            head_block(c),
+            head_block(c),
+            pl.BlockSpec((1, 1, 1, c), lambda i, h, *_: (i, h, 0, 0)),
+            head_block(r),
+            head_block(r),
+        ],
+        out_specs=rows_block,
+    )
     out = pl.pallas_call(
         functools.partial(_kernel, l=l, g=g, r=r, scale=scale,
                           softcap=softcap),
-        grid=(b, hkv),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i, h: (i,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda i, h: (i,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda i, h: (i,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, l, g, dh), lambda i, h: (i, h, 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, c, 1, dh), lambda i, h: (i, 0, h, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, c, 1, dh), lambda i, h: (i, 0, h, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, c), lambda i, h: (i, h, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, r, 1, dh), lambda i, h: (i, 0, h, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, r, 1, dh), lambda i, h: (i, 0, h, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, l, g, dh), lambda i, h: (i, h, 0, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, l, g, dh), q.dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, l * g, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(t, cov, chunk_len, qh, k_cents, v_cents, cnt_t, k_tail, v_tail)
-    out = out.transpose(0, 2, 1, 3, 4).reshape(b, l, hq, dh)
+    )(t, cov, chunk_len, qh, lanes(k_cents), lanes(v_cents), cnt_t,
+      lanes(k_tail), lanes(v_tail))
+    out = out.reshape(b, hkv, l, g, dh).transpose(0, 2, 1, 3, 4).reshape(
+        b, l, hq, dh)
     return out[:, 0] if squeeze else out

@@ -1,11 +1,12 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode so the
+On the CPU backend the kernels execute in ``interpret=True`` mode so the
 kernel bodies are validated end to end; on a TPU backend they compile via
-Mosaic.  Above the VMEM point-budget the grouped median falls back to the
-pure-JAX two-level reduction-tree path (``core.bitserial``) — mirroring the
-paper, where datasets beyond one storage array go through the hierarchical
-merge network.
+Mosaic.  Any other backend is an error, never a silent interpret run.
+Above the VMEM point-budget the grouped median falls back to the pure-JAX
+two-level reduction-tree path (``core.bitserial``) — mirroring the paper,
+where datasets beyond one storage array go through the hierarchical merge
+network.
 """
 
 from __future__ import annotations
@@ -27,12 +28,19 @@ MAX_KERNEL_POINTS = 4096
 
 
 def interpret_default() -> bool:
-    """True when the Pallas kernels must run in interpret mode (no Mosaic
-    lowering available).  Single source of truth for backend detection —
-    every kernel wrapper (here and in the kernel modules) resolves
-    ``interpret=None`` through this helper, so the CPU fallback can't
-    drift between call sites."""
-    return jax.default_backend() != "tpu"
+    """True on the CPU backend (kernels run interpreted), False on TPU
+    (kernels compile through Mosaic); any other backend raises.  Single
+    source of truth for backend detection — every kernel wrapper (here
+    and in the kernel modules) resolves ``interpret=None`` through this
+    helper, so the choice can't drift between call sites."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"no Pallas path for backend {backend!r}: the kernels compile "
+        "through Mosaic on 'tpu' and run interpreted on 'cpu' only")
 
 
 @partial(jax.jit, static_argnames=("k", "bits", "d_block", "interpret",

@@ -23,15 +23,18 @@ casts, same dot_general contractions, same mask order), so the paged
 engine's greedy tokens match the dense engine's bit for bit — pinned in
 tests.
 
-Layout (grid = (N rows, Hkv, T tail blocks); scalar prefetch: row block
-table (N, T) and row→slot map (N,)):
-  qpos1, tw, cov  (1,)  SMEM  — per row: query position + 1 (0 ⇒ padding
-                                row, fully masked), slot ring watermark
-                                (t + chunk_len), coverage frontier
+Layout (grid = (N rows, Hkv, T tail blocks)).  Scalar prefetch, all in
+SMEM: the flattened row block table (N*T,), row→slot map (N,), and per
+row: qpos1 (query position + 1; 0 ⇒ padding row, fully masked), tw (slot
+ring watermark t + chunk_len), cov (coverage frontier), wlo (retention
+window floor).  Head-indexed operands fold heads into the lane axis so
+every block's last two dims are full or (8, 128)-aligned:
   q        (1, 1, G, Dh)  VMEM  — this row × kv-head's query
-  k_cents  (1, C, 1, Dh)  VMEM  — gathered per row via the slot map
-  counts   (1, 1, C)      VMEM  — pre-transposed (B, Hkv, C)
-  k_pool   (1, bs, 1, Dh) VMEM  — one physical tail block per grid step,
+  k_cents  (1, C, Dh)     VMEM  — lane block h of (B, C, Hkv*Dh),
+                                  gathered per row via the slot map
+  counts   (1, 1, 1, C)   VMEM  — (B, Hkv, 1, C)
+  k_pool   (1, bs, Dh)    VMEM  — lane block h of one physical tail block
+                                  of (nb, bs, Hkv*Dh) per grid step,
                                   gathered via the block table
   out      (1, 1, G, Dh)
 """
@@ -46,30 +49,29 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.clustered_decode import (_SHARD_MAP_NO_CHECK,
-                                            score_and_combine, shard_map)
+from repro.kernels.clustered_decode import score_and_combine
 
 
 def _kernel(bt_ref, slot_ref, qpos1_ref, tw_ref, cov_ref, wlo_ref, q_ref,
             kc_ref, vc_ref, cnt_ref, kp_ref, vp_ref, o_ref, kt_s, vt_s, *,
             bs: int, nblk: int, r: int, scale: float, softcap):
-    j = pl.program_id(2)
+    i, j = pl.program_id(0), pl.program_id(2)
     # stage this row's tail block j into the scratch ring at its ring
     # offsets [j*bs, (j+1)*bs) — after the last step the scratch holds the
     # same (R, Dh) f32 operand the dense kernel reads contiguously
-    kt_s[pl.ds(j * bs, bs), :] = kp_ref[0, :, 0, :].astype(jnp.float32)
-    vt_s[pl.ds(j * bs, bs), :] = vp_ref[0, :, 0, :].astype(jnp.float32)
+    kt_s[pl.ds(j * bs, bs), :] = kp_ref[0].astype(jnp.float32)
+    vt_s[pl.ds(j * bs, bs), :] = vp_ref[0].astype(jnp.float32)
 
     @pl.when(j == nblk - 1)
     def _compute():
-        qpos1 = qpos1_ref[0]
-        tw = tw_ref[0]
-        cov = cov_ref[0]
-        wlo = wlo_ref[0]
+        qpos1 = qpos1_ref[i]
+        tw = tw_ref[i]
+        cov = cov_ref[i]
+        wlo = wlo_ref[i]
         q = q_ref[0, 0].astype(jnp.float32)                  # (G, Dh)
-        kc = kc_ref[0, :, 0].astype(jnp.float32)             # (C, Dh)
-        vc = vc_ref[0, :, 0].astype(jnp.float32)
-        cnt = cnt_ref[0, 0].astype(jnp.float32)              # (C,)
+        kc = kc_ref[0].astype(jnp.float32)                   # (C, Dh)
+        vc = vc_ref[0].astype(jnp.float32)
+        cnt = cnt_ref[0, 0, 0].astype(jnp.float32)           # (C,)
 
         row_ok = qpos1 > 0                                   # padding row?
 
@@ -115,13 +117,14 @@ def paged_clustered_decode_pallas(q, k_cents, v_cents, counts, k_pool,
     c = k_cents.shape[1]
     hkv = k_cents.shape[2]
     g = hq // hkv
-    nb, bs = k_pool.shape[0], k_pool.shape[1]
+    bs = k_pool.shape[1]
     t_blocks = row_bt.shape[1]
     r = t_blocks * bs
     qh = q.reshape(n, hkv, g, dh)
-    cnt_t = counts.transpose(0, 2, 1)                        # (B, Hkv, C)
+    cnt_t = counts.transpose(0, 2, 1).reshape(-1, hkv, 1, c)  # (B, Hkv, 1, C)
+    # flat (N*T,) table: a 2-D SMEM operand pads its minor dim to 128
+    row_bt = jnp.asarray(row_bt, jnp.int32).reshape(n * t_blocks)
     row_slot = jnp.asarray(row_slot, jnp.int32)
-    row_bt = jnp.asarray(row_bt, jnp.int32)
     qpos1 = jnp.asarray(qpos1, jnp.int32)
     tw = jnp.asarray(tw, jnp.int32)
     cov = jnp.asarray(cov, jnp.int32)
@@ -129,38 +132,27 @@ def paged_clustered_decode_pallas(q, k_cents, v_cents, counts, k_pool,
         wlo = jnp.zeros_like(qpos1)
     wlo = jnp.asarray(wlo, jnp.int32)
 
+    def lanes(x):                       # (m, n, Hkv, Dh) → (m, n, Hkv*Dh)
+        return x.reshape(x.shape[0], x.shape[1], hkv * dh)
+
+    cent_block = pl.BlockSpec(
+        (1, c, dh), lambda i, h, j, bt, sl, *_: (sl[i], 0, h))
+    pool_block = pl.BlockSpec(
+        (1, bs, dh), lambda i, h, j, bt, sl, *_: (bt[i * t_blocks + j], 0, h))
+    rows_block = pl.BlockSpec((1, 1, g, dh), lambda i, h, *_: (i, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                # row_bt, row_slot
+        num_scalar_prefetch=6,     # row_bt, row_slot, qpos1, tw, cov, wlo
         grid=(n, hkv, t_blocks),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, h, j, bt, sl: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda i, h, j, bt, sl: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda i, h, j, bt, sl: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda i, h, j, bt, sl: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g, dh), lambda i, h, j, bt, sl: (i, h, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, c, 1, dh),
-                         lambda i, h, j, bt, sl: (sl[i], 0, h, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, c, 1, dh),
-                         lambda i, h, j, bt, sl: (sl[i], 0, h, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, c), lambda i, h, j, bt, sl: (sl[i], h, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bs, 1, dh),
-                         lambda i, h, j, bt, sl: (bt[i, j], 0, h, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bs, 1, dh),
-                         lambda i, h, j, bt, sl: (bt[i, j], 0, h, 0),
-                         memory_space=pltpu.VMEM),
+            rows_block,
+            cent_block,
+            cent_block,
+            pl.BlockSpec((1, 1, 1, c),
+                         lambda i, h, j, bt, sl, *_: (sl[i], h, 0, 0)),
+            pool_block,
+            pool_block,
         ],
-        out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda i, h, j, bt, sl: (i, h, 0, 0),
-                               memory_space=pltpu.VMEM),
+        out_specs=rows_block,
         scratch_shapes=[
             pltpu.VMEM((r, dh), jnp.float32),
             pltpu.VMEM((r, dh), jnp.float32),
@@ -168,20 +160,18 @@ def paged_clustered_decode_pallas(q, k_cents, v_cents, counts, k_pool,
     )
     kernel = functools.partial(_kernel, bs=bs, nblk=t_blocks, r=r,
                                scale=scale, softcap=softcap)
-    call_kwargs = dict(interpret=interpret)
-    if not interpret:
-        # rows/heads may split across cores (each core's scratch ring is
-        # private); the tail-block walk must stay sequential per (row,
-        # head) so the staging completes before the compute step
-        call_kwargs["compiler_params"] = dict(mosaic=dict(
-            dimension_semantics=("parallel", "parallel", "arbitrary")))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, hkv, g, dh), q.dtype),
-        **call_kwargs,
-    )(row_bt, row_slot, qpos1, tw, cov, wlo, qh, k_cents, v_cents, cnt_t,
-      k_pool, v_pool)
+        # rows/heads may split across cores (each core's scratch ring is
+        # private); the tail-block walk must stay sequential per (row,
+        # head) so the staging completes before the compute step
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(row_bt, row_slot, qpos1, tw, cov, wlo, qh, lanes(k_cents),
+      lanes(v_cents), cnt_t, lanes(k_pool), lanes(v_pool))
     return out.reshape(n, hq, dh)
 
 
@@ -216,7 +206,7 @@ def paged_clustered_decode_shardmap(q, k_cents, v_cents, counts, k_pool,
             q, kc, vc, cnt, kp, vp, rs, rbt, qp1, tw_, cov_, wlo_,
             scale=scale, softcap=softcap, interpret=interpret)
 
-    f = shard_map(
+    f = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -234,7 +224,7 @@ def paged_clustered_decode_shardmap(q, k_cents, v_cents, counts, k_pool,
             P(d),                 # wlo      (N,) retention window floor
         ),
         out_specs=P(d, m, None),
-        **_SHARD_MAP_NO_CHECK,
+        check_vma=False,  # the Pallas call has no replication rule
     )
     return f(q, k_cents, v_cents, counts, k_pool, v_pool, row_slot, row_bt,
              qpos1, tw, cov, wlo)

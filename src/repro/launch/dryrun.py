@@ -54,6 +54,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         "shape": shape_name,
         "mesh": "2x16x16" if multi_pod else "16x16",
         "n_devices": 512 if multi_pod else 256,
+        # the chip the production mesh stands for (launch/mesh.py); the
+        # compile itself runs on host devices
+        "device_kind": "TPU v5 lite",
         "info": info,
         "trace_s": round(t1 - t0, 1),
         "compile_s": round(t2 - t1, 1),

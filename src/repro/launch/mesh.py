@@ -13,16 +13,8 @@ import jax
 
 
 def _mesh(shape, axes):
-    """Auto-typed mesh across jax versions: ``axis_types`` (and AxisType
-    itself) only exist on newer jax; older versions are Auto-only."""
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(at.Auto,) * len(axes))
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

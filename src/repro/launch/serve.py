@@ -43,6 +43,7 @@ from repro import configs  # noqa: E402
 from repro.core.request_cluster import (Request, plan_batches,  # noqa: E402
                                         plan_fifo)
 from repro.core import kv_compress  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_serving_mesh  # noqa: E402
 from repro.models import transformer as tfm  # noqa: E402
 from repro.runtime.kv_pool import PagedKVConfig  # noqa: E402
@@ -147,8 +148,8 @@ def main():
               f"layers under WindowRetention(window="
               f"{cfg.sliding_window}); global layers retire at the "
               f"cov frontier")
+    enable_compile_cache()
     rng = np.random.default_rng(args.seed)
-    params = tfm.init_params(jax.random.PRNGKey(args.seed), cfg)
 
     lens = np.where(rng.random(args.requests) < 0.5,
                     rng.integers(8, 24, args.requests),
@@ -199,6 +200,10 @@ def main():
         mesh = make_serving_mesh(args.mesh)
         print(f"[serve] mesh {args.mesh}: slots over data={mesh.shape['data']}"
               f", heads over model={mesh.shape['model']}")
+    # weight matrices stored at cfg.dtype, built in place (the f32 tree of
+    # a full-size config does not fit one chip)
+    params = tfm.init_params_serving(jax.random.PRNGKey(args.seed), cfg,
+                                     mesh=mesh)
     ccfg = paged = None
     clustered = args.paged or any(
         v is not None for v in (args.kv_clusters, args.keep_recent,

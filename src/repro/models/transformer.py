@@ -405,6 +405,40 @@ def init_params(rng, cfg: ModelConfig):
     return p
 
 
+# matrices the compute reads in float32 (MoE routing, RG-LRU gates): their
+# storage stays f32 so serving-dtype storage never changes a result
+_F32_AT_USE = frozenset({"router", "wa_gate", "wi_gate"})
+
+
+def init_params_serving(rng, cfg: ModelConfig, mesh=None):
+    """``init_params`` with every weight matrix stored at ``cfg.dtype``.
+
+    Compute already casts those matrices to ``cfg.dtype`` at use, so the
+    model's outputs are unchanged; vectors (norm scales, biases, SSM
+    constants) stay f32.  One jitted call draws and casts on the device:
+    the f32 draws are compiler temporaries, so a model whose f32 tree
+    would not fit (qwen3-4b: ~16 GB f32 vs ~8 GB bf16) is built in
+    place.  With a serving ``mesh`` the leaves are built directly in the
+    serving engine's placement, never whole on one device first."""
+    dt = cdtype(cfg)
+
+    def storage(path, x):
+        stacked = any(getattr(k, "key", None) == "scan" for k in path)
+        name = getattr(path[-1], "key", None)
+        if x.ndim - stacked >= 2 and name not in _F32_AT_USE:
+            return x.astype(dt)
+        return x
+
+    def build(r):
+        return jax.tree_util.tree_map_with_path(storage, init_params(r, cfg))
+
+    shardings = None
+    if mesh is not None:
+        from repro.sharding import serving_param_shardings
+        shardings = serving_param_shardings(jax.eval_shape(build, rng), mesh)
+    return jax.jit(build, out_shardings=shardings)(rng)
+
+
 # ---------------------------------------------------------------------------
 # Trunk forward (training)
 # ---------------------------------------------------------------------------

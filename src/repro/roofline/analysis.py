@@ -11,7 +11,9 @@ numerators — the division by chips cancels, so terms are computed from the
 per-device values directly).  Wire-byte factors: ring all-reduce moves
 ≈2× the tensor per device; all-gather/reduce-scatter/all-to-all/permute ≈1×.
 
-Hardware model (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: ``PEAKS``, keyed by the device kind a record names (as
+JAX reports it, ``jax.devices()[0].device_kind``).  A kind missing from
+the table is an error, never a default.
 
 MODEL_FLOPS uses 6·N·D (train) or 2·N·D (forward-only), with N = active
 params for MoE; the ratio MODEL_FLOPS/HLO_FLOPs exposes remat/redundancy
@@ -23,11 +25,31 @@ from __future__ import annotations
 import glob
 import json
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # B/s / chip
-LINK_BW = 50e9               # B/s / link
+
+class Peaks(NamedTuple):
+    flops: float             # bf16 FLOP/s per chip
+    hbm_bw: float            # HBM bytes/s per chip
+    link_bw: float           # ICI bytes/s per link
+    hbm_bytes: float         # HBM capacity per chip
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s ICI per chip over 4 links (50 GB/s each)
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, link_bw=50e9,
+                         hbm_bytes=16e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """Published peaks of one chip of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})") from None
 
 WIRE_FACTOR = {"all-reduce": 2.0, "all-gather": 1.0, "reduce-scatter": 1.0,
                "all-to-all": 1.0, "collective-permute": 1.0}
@@ -53,15 +75,16 @@ def analyze_record(rec: dict) -> Optional[dict]:
         return None
     hs = rec["hlo_stats"]
     chips = rec["n_devices"]
+    peak = peaks_for(rec["device_kind"])
     flops_dev = hs["flops"]
     # fused byte model (TPU-like) when available, else conservative
     hbm_dev = hs.get("hbm_bytes_fused", hs["hbm_bytes"])
     wire_dev = sum(WIRE_FACTOR.get(k, 1.0) * v
                    for k, v in hs["collectives"].items())
 
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = hbm_dev / HBM_BW
-    t_coll = wire_dev / LINK_BW
+    t_compute = flops_dev / peak.flops
+    t_memory = hbm_dev / peak.hbm_bw
+    t_coll = wire_dev / peak.link_bw
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops_global(rec)
@@ -69,13 +92,13 @@ def analyze_record(rec: dict) -> Optional[dict]:
     useful = mf / hlo_global if hlo_global else 0.0
     # ideal step time: compute floor, and for serving steps also the
     # unavoidable HBM floor (params + cache must be read once per step)
-    t_ideal = (mf / chips) / PEAK_FLOPS
+    t_ideal = (mf / chips) / peak.flops
     from repro.models.config import SHAPES
     step_kind = SHAPES[rec["shape"]].step
     if step_kind == "decode":
         floor_bytes = (2.0 * rec["info"]["active_params"]
                        + rec["info"].get("cache_bytes", 0)) / chips
-        t_ideal = max(t_ideal, floor_bytes / HBM_BW)
+        t_ideal = max(t_ideal, floor_bytes / peak.hbm_bw)
     # roofline fraction: ideal over the dominant term's cost
     t_dom = terms[dominant]
     frac = t_ideal / t_dom if t_dom > 0 else 0.0
@@ -91,7 +114,7 @@ def analyze_record(rec: dict) -> Optional[dict]:
         "useful_ratio": round(useful, 4),
         "roofline_fraction": round(frac, 4),
         "device_bytes": hbm_per_dev,
-        "fits_16gb": hbm_per_dev < 16e9,
+        "fits_16gb": hbm_per_dev < peak.hbm_bytes,
         "collectives_dev": hs["collectives"],
         "unknown_trip_loops": hs.get("unknown_trip_loops", 0),
     }

@@ -89,8 +89,8 @@ from repro.runtime.telemetry import TelemetryConfig
 from repro.sharding import (Rules, constrain_cache, default_table,
                             place_admission, place_block_tables,
                             place_prefix_snapshot, place_swap_payload,
-                            serving_param_specs, shard_cache,
-                            shardings_from_specs, use_rules)
+                            serving_param_shardings, shard_cache,
+                            use_rules)
 from repro.sharding.rules import _key_str as _key_name
 
 
@@ -228,6 +228,14 @@ def _slot_resize(x, axis: int, shards: int, ob: int, nb: int):
     return xr.reshape(lead + (shards * nb,) + rest)
 
 
+def _greedy(logits):
+    """Greedy tokens of one launch and its count of non-finite logits,
+    fetched to host together."""
+    nxt, bad = jax.device_get((jnp.argmax(logits, -1),
+                               jnp.sum(~jnp.isfinite(logits))))
+    return np.asarray(nxt).astype(np.int32), int(bad)
+
+
 def _percentile_ms(vals: List[float], q: float) -> float:
     if not vals:
         return 0.0
@@ -352,9 +360,8 @@ class Server:
             # leaves whose replication cost dominates); everything else
             # replicates and the annotate/shard_map islands shard the
             # per-head compute, GSPMD propagation does the rest
-            params = jax.device_put(
-                params, shardings_from_specs(
-                    mesh, serving_param_specs(params, self._rules)))
+            params = jax.device_put(params,
+                                    serving_param_shardings(params, mesh))
             axes = self._rules.axes_for("batch", scfg.batch_size)
             if axes:
                 self._n_data_shards = math.prod(
@@ -379,13 +386,15 @@ class Server:
         # invalidates instead of adopting stale state.  The weight stamp
         # is a content hash, not object identity, so reloaded identical
         # params (a new pytree with the same bytes) keep a warm store.
+        # Only a store reads it: hashing copies every weight byte to host.
         self._tmpl_pool: Optional[kv_pool.BlockPool] = None
         self._tmpl_cache = None
         self._store_epoch = (repr(cfg), repr(scfg.kv_compress),
                              repr(scfg.paged), scfg.prefill_chunk,
                              scfg.max_seq, scfg.batch_size,
                              self._n_data_shards,
-                             self._params_digest(self.params))
+                             self._params_digest(self.params)
+                             if self._store is not None else None)
         # layer-state families (core/layer_state.py): which state each
         # layer carries per slot — ring-KV ('G'/'L', retention-governed)
         # vs fixed-size recurrent state ('M'/'R', checkpointed whole).
@@ -410,25 +419,28 @@ class Server:
             return (use_rules(self._rules) if self._rules is not None
                     else contextlib.nullcontext())
 
-        def _decode_fn(c, tk, t):
+        # the step functions take the weights as an argument: a jitted
+        # closure over them would embed every weight byte in the program
+        # as a constant
+        def _decode_fn(params, c, tk, t):
             with _ctx():
-                logits, c2 = tfm.decode_step(self.params, cfg, c, tk, t)
+                logits, c2 = tfm.decode_step(params, cfg, c, tk, t)
                 return logits, self._constrain(c2)
 
-        def _mixed_fn(c, tk, t, cl):
+        def _mixed_fn(params, c, tk, t, cl):
             with _ctx():
-                logits, c2 = tfm.decode_step(self.params, cfg, c, tk, t,
+                logits, c2 = tfm.decode_step(params, cfg, c, tk, t,
                                              chunk_len=cl)
                 return logits, self._constrain(c2)
 
-        def _prefill_fn(tk, lp):
+        def _prefill_fn(params, tk, lp):
             with _ctx():
                 # recurrent layers prefill SEQUENTIALLY when served: the
                 # parallel scan forms (ssd_chunked / associative scan)
                 # are mathematically equal but not bitwise equal to
                 # stepwise decode, and serving pins chunked/paged tokens
                 # bit-identical to blocking one-at-a-time decode
-                return tfm.prefill(self.params, cfg, tk,
+                return tfm.prefill(params, cfg, tk,
                                    max_seq=scfg.max_seq, last_pos=lp,
                                    recurrent_mode=("sequential"
                                                    if self._has_recurrent
@@ -461,10 +473,10 @@ class Server:
         if self._paged is not None:
             blk = self._paged.block_size
 
-            def _packed_fn(c, tk, rs, rp, rtw, rcidx, bt, width):
+            def _packed_fn(params, c, tk, rs, rp, rtw, rcidx, bt, width):
                 with _ctx():
                     logits, c2 = tfm.decode_step_packed(
-                        self.params, cfg, c, tk, rs, rp, rtw, rcidx, bt,
+                        params, cfg, c, tk, rs, rp, rtw, rcidx, bt,
                         block_size=blk, width=width)
                     return logits, self._constrain(c2)
 
@@ -511,8 +523,8 @@ class Server:
             # ``width`` (max chunk index + 1, sequencing sliding-window
             # ring commits) is static: exactly two traces — the mixed
             # shape (width = prefill_chunk) and pure decode (width = 1)
-            self._decode_packed = jax.jit(_packed_fn, donate_argnums=(0,),
-                                          static_argnums=(7,))
+            self._decode_packed = jax.jit(_packed_fn, donate_argnums=(1,),
+                                          static_argnums=(8,))
             self._write_slot_paged = jax.jit(_write_slot_paged_fn,
                                              donate_argnums=(0,))
             self._absorb_paged = jax.jit(_absorb_paged_fn,
@@ -814,7 +826,7 @@ class Server:
         decode_steps = wasted_slots = 0
         rows_launched = 0
         pad_toks = useful_toks = 0
-        n_chunks = n_absorbs = n_compacts = 0
+        n_chunks = n_absorbs = n_compacts = n_bad_logits = 0
         # compaction cadence is per-slot decode progress, not engine
         # steps: a slot's ring only advances when that slot decodes, so
         # chunk-feed steps for OTHER slots must not inflate the schedule
@@ -1282,7 +1294,7 @@ class Server:
             return True
 
         def admit_blocking(j, uid) -> bool:
-            nonlocal cache, pad_toks, useful_toks
+            nonlocal cache, pad_toks, useful_toks, n_bad_logits
             r = by_uid[uid]
             p = np.asarray(prompts[uid], np.int32)[-scfg.max_seq:]
             plen = len(p)
@@ -1314,9 +1326,11 @@ class Server:
             padded = np.zeros((1, bkt), np.int32)
             padded[0, :plen] = p
             t0 = time.perf_counter()
-            logits1, c1 = self._prefill(jnp.asarray(padded),
+            logits1, c1 = self._prefill(self.params, jnp.asarray(padded),
                                         jnp.int32(plen - 1))
-            first = int(jnp.argmax(logits1, -1)[0])
+            nxt1, bad = _greedy(logits1)
+            first = int(nxt1[0])
+            n_bad_logits += bad
             now = time.perf_counter()
             pre_ms[uid] = (now - t0_serve) * 1e3        # TTFT
             tr_open(j, uid, t0, "admit", p0=0)
@@ -1676,10 +1690,11 @@ class Server:
                 t0 = time.perf_counter()
                 with _annot("decode_packed"):
                     logits, cache = self._decode_packed(
-                        cache, jnp.asarray(tokp), jnp.asarray(rslot),
-                        jnp.asarray(rpos), jnp.asarray(rtw),
+                        self.params, cache, jnp.asarray(tokp),
+                        jnp.asarray(rslot), jnp.asarray(rpos),
+                        jnp.asarray(rtw),
                         jnp.asarray(rcidx), bt_dev, width)
-                nxt = np.asarray(jnp.argmax(logits, -1)).astype(np.int32)
+                nxt, bad = _greedy(logits)
                 nxt_of = lambda jj: nxt[last_row[jj]]      # noqa: E731
                 # launch_rows_frac / launch_bucket_mean stay SLOT
                 # bookkeeping (the slot axis never shrinks in paged
@@ -1707,21 +1722,22 @@ class Server:
                 t0 = time.perf_counter()
                 if mixed:
                     with _annot("mixed_step"):
-                        logits, cache = self._mixed(cache,
+                        logits, cache = self._mixed(self.params, cache,
                                                     jnp.asarray(tok),
                                                     jnp.asarray(t_vec),
                                                     jnp.asarray(cl_vec))
                 else:
                     with _annot("decode_step"):
-                        logits, cache = self._decode(cache,
+                        logits, cache = self._decode(self.params, cache,
                                                      jnp.asarray(tok),
                                                      jnp.asarray(t_vec))
-                nxt = np.asarray(jnp.argmax(logits, -1)).astype(np.int32)
+                nxt, bad = _greedy(logits)
                 nxt_of = lambda jj: nxt[phys(jj)]          # noqa: E731
                 rows_step, compute_rows = bp, bp * width
             now = time.perf_counter()
             dec_s += now - t0
             decode_steps += 1
+            n_bad_logits += bad
             rows_launched += rows_step
             launch_real += real_rows
             launch_padded += compute_rows
@@ -2029,6 +2045,9 @@ class Server:
                     ).add(n_absorbs)
         reg.counter("kv_compactions", "batched compaction passes"
                     ).add(n_compacts)
+        reg.counter("logits_nonfinite",
+                    "NaN/inf logit values in engine launches this serve"
+                    ).add(n_bad_logits)
         # positions each retention policy retired this serve —
         # FrontierRetention counts coverage-frontier advancement
         # (absorbs + compactions + admission clusterize, dense and
@@ -2711,14 +2730,16 @@ class Server:
             toks[i, plen - len(p):] = p  # left-pad
 
         t0 = time.perf_counter()
-        logits, cache = self._prefill(jnp.asarray(toks), jnp.int32(plen - 1))
+        logits, cache = self._prefill(self.params, jnp.asarray(toks),
+                                      jnp.int32(plen - 1))
         jax.block_until_ready(logits)
         t1 = time.perf_counter()
 
         new = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
         gen_toks = [new]
         for i in range(gen - 1):
-            logits, cache = self._decode(cache, new, jnp.int32(plen + i))
+            logits, cache = self._decode(self.params, cache, new,
+                                         jnp.int32(plen + i))
             new = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
             gen_toks.append(new)
         jax.block_until_ready(new)

@@ -33,7 +33,6 @@ Event schema (internal form)::
 
 from __future__ import annotations
 
-import contextlib
 import json
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -743,13 +742,11 @@ def validate_jsonl_file(path: str, reconcile: bool = True) -> List[str]:
 
 
 def annotation(name: str):
-    """A ``jax.profiler`` trace annotation, or a no-op if unavailable."""
-    try:
-        import jax.profiler
+    """A ``jax.profiler`` trace annotation: a host span in the profiler's
+    own trace, on the same clock as the device events."""
+    import jax.profiler
 
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 # ---------------------------------------------------------------------------

@@ -486,3 +486,12 @@ def _key_str(k) -> str:
 def shardings_from_specs(mesh: Mesh, specs):
     return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                         is_leaf=lambda s: isinstance(s, P))
+
+
+def serving_param_shardings(params, mesh: Mesh):
+    """NamedSharding pytree the serving engine places ``params`` with on
+    ``mesh``: ``serving_param_specs`` under the mesh's default rules.
+    ``params`` may be shapes (``jax.eval_shape``), so an initializer can
+    build the weights in place."""
+    rules = Rules(mesh, default_table("pod" in mesh.axis_names))
+    return shardings_from_specs(mesh, serving_param_specs(params, rules))

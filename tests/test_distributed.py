@@ -17,10 +17,7 @@ def test_distributed_median_matches_single_device():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:  # jax < 0.5: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import bitserial, quantizer
 
         assert len(jax.devices()) == 8
@@ -51,10 +48,7 @@ def test_distributed_kmedians_fit_matches_single_device():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import Mesh, PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:  # jax < 0.5: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import clustering
         from repro.core.clustering import ClusterConfig
 
@@ -96,10 +90,7 @@ def test_distributed_weighted_compress_head_matches_single_device():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:  # jax < 0.5: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import kv_compress
 
         rng = np.random.default_rng(2)

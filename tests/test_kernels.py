@@ -146,11 +146,25 @@ class TestFlashDecodeKernel:
 class TestInterpretFallback:
     """``ops.interpret_default`` is the single backend-detection point for
     every Pallas wrapper; on the CPU backend it must flip all of them into
-    interpret mode (a Mosaic attempt would fail outright here)."""
+    interpret mode (a Mosaic attempt would fail outright here), on TPU
+    into Mosaic, and anywhere else it refuses."""
 
     def test_detects_cpu(self):
-        assert jax.default_backend() != "tpu"  # this container's contract
+        assert jax.default_backend() == "cpu"  # the tests' contract
         assert ops.interpret_default() is True
+
+    @pytest.mark.parametrize("backend,want", [("tpu", False), ("gpu", None),
+                                              ("METAL", None)])
+    def test_tpu_compiles_and_other_backends_raise(self, monkeypatch,
+                                                   backend, want):
+        """Only 'cpu' interprets and only 'tpu' compiles; any other
+        backend is refused instead of silently interpreting."""
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        if want is None:
+            with pytest.raises(RuntimeError, match=backend):
+                ops.interpret_default()
+        else:
+            assert ops.interpret_default() is want
 
     def test_clustered_decode_resolves_none_via_helper(self):
         """interpret=None (the default) must run on CPU — i.e. the kernel
