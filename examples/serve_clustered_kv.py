@@ -58,7 +58,7 @@ def main():
     outs = srv.serve(reqs, prompts)
     st = srv.last_stats
     print(f"[server] continuous batching: {len(outs)} completions, "
-          f"{st['tokens_per_s']:.1f} tok/s, slot waste "
+          f"{st['tokens_per_s_wall']:.1f} tok/s, slot waste "
           f"{st['slot_waste'] * 100:.1f}%")
 
     # --- chunked prefill interleaved with decode (--prefill-chunk) ---
@@ -103,8 +103,8 @@ def main():
                                      sorted(outs, key=lambda o: o.uid))])
     print(f"[server] clustered-KV + compaction (+chunked admission, "
           f"{srv_c.last_stats['kv_absorbs']:.0f} absorbs): "
-          f"{srv_c.last_stats['tokens_per_s']:.1f} tok/s, token agreement "
-          f"vs exact serving {agree * 100:.0f}%")
+          f"{srv_c.last_stats['tokens_per_s_wall']:.1f} tok/s, token "
+          f"agreement vs exact serving {agree * 100:.0f}%")
 
     # --- paged clustered-KV memory manager (ServerConfig.paged) ---
     # The engines above allocate every slot's exact tail as a full dense
@@ -431,7 +431,7 @@ def main():
         by_uid = {o.uid: o.tokens for o in outs_c}
         exact = all(o.tokens == by_uid[o.uid] for o in outs_m)
         print(f"[server] mesh {spec}: "
-              f"{srv_m.last_stats['tokens_per_s']:.1f} tok/s, tokens "
+              f"{srv_m.last_stats['tokens_per_s_wall']:.1f} tok/s, tokens "
               f"{'bit-identical' if exact else 'DIVERGED'} vs single-device")
     else:
         print("[server] mesh serving skipped (1 device; set XLA_FLAGS="

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import bitserial, quantizer
+from repro.runtime.telemetry import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,11 +159,6 @@ def seed_empty_centroids(x, cents, live, metric: str, weights=None):
     k = cents.shape[0]
     w = (jnp.ones((n,), jnp.float32) if weights is None
          else weights.astype(jnp.float32))
-    dist0 = pairwise_dist(x, cents, metric)               # (n, K)
-    mind = jnp.min(jnp.where(live[None, :], dist0, jnp.inf), axis=1)
-    # no live row yet → flat field: the first dead row takes the first
-    # positively-weighted point, the rest spread by maximin from there
-    mind = jnp.where(jnp.isfinite(mind), mind, 1.0)
 
     def body(i, carry):
         cents, mind = carry
@@ -173,7 +169,13 @@ def seed_empty_centroids(x, cents, live, metric: str, weights=None):
         d_new = pairwise_dist(x, c_i[None, :], metric)[:, 0]
         return cents, jnp.minimum(mind, d_new)
 
-    cents, _ = jax.lax.fori_loop(0, k, body, (cents, mind))
+    with scope("kmedians_reseed"):
+        dist0 = pairwise_dist(x, cents, metric)           # (n, K)
+        mind = jnp.min(jnp.where(live[None, :], dist0, jnp.inf), axis=1)
+        # no live row yet → flat field: the first dead row takes the first
+        # positively-weighted point, the rest spread by maximin from there
+        mind = jnp.where(jnp.isfinite(mind), mind, 1.0)
+        cents, _ = jax.lax.fori_loop(0, k, body, (cents, mind))
     return cents
 
 
@@ -220,15 +222,17 @@ class ClusterResult(NamedTuple):
 
 def _one_iter(cfg: ClusterConfig, x, cents, scale, axis_name=None,
               use_kernel=True, weights=None):
-    assign, mind = assign_points(x, cents, cfg.metric, cfg.assign_chunk,
-                                 use_kernel=use_kernel)
+    with scope("kmedians_assign"):
+        assign, mind = assign_points(x, cents, cfg.metric, cfg.assign_chunk,
+                                     use_kernel=use_kernel)
     if cfg.centroid == "mean":
         new, counts = update_mean(x, assign, cfg.k, cents, weights=weights,
                                   axis_name=axis_name)
     else:
-        new, counts = update_median(x, assign, cfg.k, cents, bits=cfg.bits,
-                                    scale=scale, weights=weights,
-                                    axis_name=axis_name)
+        with scope("kmedians_median"):
+            new, counts = update_median(x, assign, cfg.k, cents,
+                                        bits=cfg.bits, scale=scale,
+                                        weights=weights, axis_name=axis_name)
     inertia = mind.sum() if weights is None else (mind * weights).sum()
     if axis_name is not None:
         inertia = jax.lax.psum(inertia, axis_name)

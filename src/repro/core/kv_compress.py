@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.core import bitserial, clustering
 from repro.core.clustering import ClusterConfig
+from repro.runtime.telemetry import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -229,29 +230,31 @@ def recompact_clustered(cache, lengths, cfg: KVCompressConfig,
     Per-slot determinism is what lets the engine compact slots on their
     own cadence and admit prefix-shared requests on a different schedule
     without changing anyone's tokens."""
-    k_cents = cache["k_cents"].astype(jnp.float32)     # (B, C, H, Dh)
-    v_cents = cache["v_cents"].astype(jnp.float32)
-    counts = cache["counts"]                           # (B, C, H)
-    k_tail = cache["k_tail"].astype(jnp.float32)       # (B, R, H, Dh)
-    v_tail = cache["v_tail"].astype(jnp.float32)
-    cov = cache["cov"]                                 # (B,)
-    b, c, h, dh = k_cents.shape
-    r = k_tail.shape[1]
-    lengths = jnp.asarray(lengths)
-    # frontier is monotone even for drained slots (engine passes length 0
-    # for finished slots; their cov must not regress and re-admit tail
-    # entries already folded into centroids)
-    new_cov = jnp.maximum(cov, jnp.clip(lengths - r + cfg.refresh,
-                                        0, lengths))
+    with scope("compact_gather"):
+        k_cents = cache["k_cents"].astype(jnp.float32)  # (B, C, H, Dh)
+        v_cents = cache["v_cents"].astype(jnp.float32)
+        counts = cache["counts"]                        # (B, C, H)
+        k_tail = cache["k_tail"].astype(jnp.float32)    # (B, R, H, Dh)
+        v_tail = cache["v_tail"].astype(jnp.float32)
+        cov = cache["cov"]                              # (B,)
+        b, c, h, dh = k_cents.shape
+        r = k_tail.shape[1]
+        lengths = jnp.asarray(lengths)
+        # frontier is monotone even for drained slots (engine passes
+        # length 0 for finished slots; their cov must not regress and
+        # re-admit tail entries already folded into centroids)
+        new_cov = jnp.maximum(cov, jnp.clip(lengths - r + cfg.refresh,
+                                            0, lengths))
 
-    ring_pos = ring_positions(r, lengths)              # (B, R)
-    w_tail = ((ring_pos >= cov[:, None])
-              & (ring_pos < new_cov[:, None])).astype(jnp.float32)
+        ring_pos = ring_positions(r, lengths)           # (B, R)
+        w_tail = ((ring_pos >= cov[:, None])
+                  & (ring_pos < new_cov[:, None])).astype(jnp.float32)
 
     def one_head(kc, vc, cnt, kt, vt, wt):
-        x = jnp.concatenate([kc, kt], axis=0)          # (C + R, Dh)
-        vals = jnp.concatenate([vc, vt], axis=0)
-        wgt = jnp.concatenate([cnt, wt], axis=0)
+        with scope("compact_gather"):
+            x = jnp.concatenate([kc, kt], axis=0)       # (C + R, Dh)
+            vals = jnp.concatenate([vc, vt], axis=0)
+            wgt = jnp.concatenate([cnt, wt], axis=0)
         return compress_head(x, vals, cfg, weights=wgt, init_centroids=kc,
                              axis_name=axis_name)
 
@@ -262,16 +265,17 @@ def recompact_clustered(cache, lengths, cfg: KVCompressConfig,
 
     nk, nv, ncnt = jax.vmap(one_slot)(k_cents, v_cents, counts,
                                       k_tail, v_tail, w_tail)
-    changed = (new_cov > cov)[:, None, None]
-    return dict(
-        cache,
-        k_cents=jnp.where(changed[..., None], nk.transpose(0, 2, 1, 3),
-                          k_cents).astype(cache["k_cents"].dtype),
-        v_cents=jnp.where(changed[..., None], nv.transpose(0, 2, 1, 3),
-                          v_cents).astype(cache["v_cents"].dtype),
-        counts=jnp.where(changed, ncnt.transpose(0, 2, 1), counts),
-        cov=new_cov.astype(jnp.int32),
-    )
+    with scope("compact_write"):
+        changed = (new_cov > cov)[:, None, None]
+        return dict(
+            cache,
+            k_cents=jnp.where(changed[..., None], nk.transpose(0, 2, 1, 3),
+                              k_cents).astype(cache["k_cents"].dtype),
+            v_cents=jnp.where(changed[..., None], nv.transpose(0, 2, 1, 3),
+                              v_cents).astype(cache["v_cents"].dtype),
+            counts=jnp.where(changed, ncnt.transpose(0, 2, 1), counts),
+            cov=new_cov.astype(jnp.int32),
+        )
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -304,34 +308,38 @@ def absorb_chunk(cache, lengths, target_cov, cfg: KVCompressConfig):
     is only reseeded where counts are zero).
     """
     budget = cfg.prompt_budget
-    k_cents = cache["k_cents"].astype(jnp.float32)     # (B, C, H, Dh)
-    v_cents = cache["v_cents"].astype(jnp.float32)
-    counts = cache["counts"]                           # (B, C, H)
-    k_tail = cache["k_tail"].astype(jnp.float32)       # (B, R, H, Dh)
-    v_tail = cache["v_tail"].astype(jnp.float32)
-    cov = cache["cov"]                                 # (B,)
-    b, c, h, dh = k_cents.shape
-    r = k_tail.shape[1]
-    lengths = jnp.asarray(lengths)
-    new_cov = jnp.clip(jnp.maximum(cov, jnp.asarray(target_cov)), 0, lengths)
+    with scope("compact_gather"):
+        k_cents = cache["k_cents"].astype(jnp.float32)  # (B, C, H, Dh)
+        v_cents = cache["v_cents"].astype(jnp.float32)
+        counts = cache["counts"]                        # (B, C, H)
+        k_tail = cache["k_tail"].astype(jnp.float32)    # (B, R, H, Dh)
+        v_tail = cache["v_tail"].astype(jnp.float32)
+        cov = cache["cov"]                              # (B,)
+        b, c, h, dh = k_cents.shape
+        r = k_tail.shape[1]
+        lengths = jnp.asarray(lengths)
+        new_cov = jnp.clip(jnp.maximum(cov, jnp.asarray(target_cov)), 0,
+                           lengths)
 
-    ring_pos = ring_positions(r, lengths)              # (B, R)
-    w_tail = ((ring_pos >= cov[:, None])
-              & (ring_pos < new_cov[:, None])).astype(jnp.float32)
+        ring_pos = ring_positions(r, lengths)           # (B, R)
+        w_tail = ((ring_pos >= cov[:, None])
+                  & (ring_pos < new_cov[:, None])).astype(jnp.float32)
     bcfg = dataclasses.replace(cfg, n_clusters=budget)
 
     def one_head(kc, vc, cnt, kt, vt, wt, fresh):
-        x = jnp.concatenate([kc, kt], axis=0)          # (C + R, Dh)
-        vals = jnp.concatenate([vc, vt], axis=0)
-        wgt = jnp.concatenate([cnt, wt], axis=0)
+        with scope("compact_gather"):
+            x = jnp.concatenate([kc, kt], axis=0)       # (C + R, Dh)
+            vals = jnp.concatenate([vc, vt], axis=0)
+            wgt = jnp.concatenate([cnt, wt], axis=0)
         init = clustering.seed_empty_centroids(
             x, kc[:budget], cnt[:budget] > 0, cfg.metric,
             weights=wgt * fresh)
         nk, nv, ncnt = compress_head(x, vals, bcfg, weights=wgt,
                                      init_centroids=init)
-        return (kc.at[:budget].set(nk), vc.at[:budget].set(nv),
-                jnp.concatenate([ncnt, jnp.zeros((c - budget,),
-                                                 ncnt.dtype)]))
+        with scope("compact_write"):
+            return (kc.at[:budget].set(nk), vc.at[:budget].set(nv),
+                    jnp.concatenate([ncnt, jnp.zeros((c - budget,),
+                                                     ncnt.dtype)]))
 
     def one_slot(kc, vc, cnt, kt, vt, wt, fresh):
         return jax.vmap(lambda *a: one_head(*a, wt, fresh))(
@@ -343,19 +351,20 @@ def absorb_chunk(cache, lengths, target_cov, cfg: KVCompressConfig):
     fresh = (new_cov > cov).astype(jnp.float32)
     nk, nv, ncnt = jax.vmap(one_slot)(k_cents, v_cents, counts,
                                       k_tail, v_tail, w_tail, fresh)
-    changed = (new_cov > cov)[:, None, None]
-    out_counts = jnp.where(changed, ncnt.transpose(0, 2, 1), counts)
-    return dict(
-        cache,
-        k_cents=jnp.where(changed[..., None], nk.transpose(0, 2, 1, 3),
-                          cache["k_cents"].astype(jnp.float32)
-                          ).astype(cache["k_cents"].dtype),
-        v_cents=jnp.where(changed[..., None], nv.transpose(0, 2, 1, 3),
-                          cache["v_cents"].astype(jnp.float32)
-                          ).astype(cache["v_cents"].dtype),
-        counts=out_counts,
-        cov=new_cov.astype(jnp.int32),
-    )
+    with scope("compact_write"):
+        changed = (new_cov > cov)[:, None, None]
+        out_counts = jnp.where(changed, ncnt.transpose(0, 2, 1), counts)
+        return dict(
+            cache,
+            k_cents=jnp.where(changed[..., None], nk.transpose(0, 2, 1, 3),
+                              cache["k_cents"].astype(jnp.float32)
+                              ).astype(cache["k_cents"].dtype),
+            v_cents=jnp.where(changed[..., None], nv.transpose(0, 2, 1, 3),
+                              cache["v_cents"].astype(jnp.float32)
+                              ).astype(cache["v_cents"].dtype),
+            counts=out_counts,
+            cov=new_cov.astype(jnp.int32),
+        )
 
 
 def clustered_attention(q, ckv: CompressedKV, *, scale: float):

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro.models.config import ModelConfig
 from repro.models.layers import (apply_rope, cdtype, dense_init, rms_head_norm,
                                  rng_for)
+from repro.runtime.telemetry import scope
 from repro.sharding import annotate
 
 NEG = -1e30
@@ -488,10 +489,11 @@ def attn_decode_clustered_packed(p, x, cfg: ModelConfig, *, cache,
     valid = row_pos >= 0
     blk = jnp.where(valid, blk, nb)                       # pad rows drop
     off = roff % block_size
-    k_pool = cache["k_tail"].at[blk, off].set(
-        k.astype(cache["k_tail"].dtype), mode="drop")
-    v_pool = cache["v_tail"].at[blk, off].set(
-        v.astype(cache["v_tail"].dtype), mode="drop")
+    with scope("kv_pool_write"):
+        k_pool = cache["k_tail"].at[blk, off].set(
+            k.astype(cache["k_tail"].dtype), mode="drop")
+        v_pool = cache["v_tail"].at[blk, off].set(
+            v.astype(cache["v_tail"].dtype), mode="drop")
 
     qpos1 = jnp.where(valid, row_pos + 1, 0)
     row_cov = jnp.take(cache["cov"], row_slot, axis=0)
@@ -501,10 +503,12 @@ def attn_decode_clustered_packed(p, x, cfg: ModelConfig, *, cache,
         row_wlo = jnp.zeros_like(qpos1)
     hq = cfg.n_heads
     from repro.kernels import ops as kops
-    out = kops.paged_clustered_decode(
-        q[:, 0], cache["k_cents"], cache["v_cents"], cache["counts"],
-        k_pool, v_pool, row_slot, row_bt, qpos1, row_tw, row_cov,
-        row_wlo=row_wlo, scale=_scale(cfg), softcap=cfg.attn_logit_softcap)
+    with scope("paged_attention"):
+        out = kops.paged_clustered_decode(
+            q[:, 0], cache["k_cents"], cache["v_cents"], cache["counts"],
+            k_pool, v_pool, row_slot, row_bt, qpos1, row_tw, row_cov,
+            row_wlo=row_wlo, scale=_scale(cfg),
+            softcap=cfg.attn_logit_softcap)
     # same head-gather-before-wo rule as the dense clustered path
     out_flat = annotate(out.reshape(n, 1, hq * cfg.head_dim),
                         "batch", "seq", None)

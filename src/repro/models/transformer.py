@@ -28,6 +28,7 @@ from repro.models.layers import (apply_frontend, apply_mlp, apply_norm,
                                  init_embed, init_frontend, init_mlp,
                                  init_norm, lm_logits, rng_for,
                                  sinusoidal_pos)
+from repro.runtime.telemetry import scope
 from repro.sharding import annotate
 
 
@@ -85,16 +86,17 @@ def init_sublayer(rng, cfg: ModelConfig, kind: str, use_moe: bool,
 
 def _ffn(p, h, cfg: ModelConfig):
     """norm2 → (moe|mlp) → residual (+sandwich norm).  Returns (h, aux)."""
-    x = apply_norm(p["norm2"], h, cfg)
-    if "moe" in p:
-        y, metrics = moe_mod.apply_moe(p["moe"], x, cfg)
-        aux = metrics["aux_loss"]
-    else:
-        y = apply_mlp(p["mlp"], x, cfg)
-        aux = jnp.float32(0.0)
-    if cfg.post_norms:
-        y = apply_norm(p["post_mlp_norm"], y, cfg)
-    return h + y, aux
+    with scope("mlp"):
+        x = apply_norm(p["norm2"], h, cfg)
+        if "moe" in p:
+            y, metrics = moe_mod.apply_moe(p["moe"], x, cfg)
+            aux = metrics["aux_loss"]
+        else:
+            y = apply_mlp(p["mlp"], x, cfg)
+            aux = jnp.float32(0.0)
+        if cfg.post_norms:
+            y = apply_norm(p["post_mlp_norm"], y, cfg)
+        return h + y, aux
 
 
 def sublayer_train(p, h, cfg: ModelConfig, kind: str, *, positions,
@@ -991,6 +993,7 @@ def decode_step_packed(params, cfg: ModelConfig, cache, tokens, row_slot,
         h, c2 = step(lp, h, cache["tail"][i], kind)
         new_cache["tail"].append(c2)
 
-    h = apply_norm(params["final_norm"], h, cfg)
-    logits = lm_logits(params["embed"], h, cfg)[:, 0]
+    with scope("lm_head"):
+        h = apply_norm(params["final_norm"], h, cfg)
+        logits = lm_logits(params["embed"], h, cfg)[:, 0]
     return logits, new_cache

@@ -378,6 +378,13 @@ class Server:
         self.tracer = (tele_mod.Tracer(self._tele.max_events)
                        if self._tele.trace else None)
         self.last_trace: List[dict] = []
+        # host spans on the profiler clock (telemetry.TRACE_NAMES)
+        self._annot = (tele_mod.annotation if self._tele.jax_profiler
+                       else tele_mod.no_annotation)
+        # programs built from here on: programs_built_total and
+        # program_build_s_total count from this mark
+        self._builds = tele_mod.program_builds()
+        self._builds0 = (self._builds.n, self._builds.seconds)
         # cross-serve template persistence: the pool (host tables/refs)
         # and the device engine cache that carry the store's pinned
         # blocks between serve() calls.  The config epoch stamps every
@@ -561,9 +568,17 @@ class Server:
     def serve(self, requests: Sequence[Request],
               prompts: Dict[int, np.ndarray]) -> List[Completion]:
         """prompts: uid -> token array.  Returns completions per request."""
-        if self.scfg.engine == "continuous":
-            return self._serve_continuous(requests, prompts)
-        return self._serve_static(requests, prompts)
+        with self._annot("serve"):
+            if self.scfg.engine != "continuous":
+                return self._serve_static(requests, prompts)
+            with self._builds.watch(self._trace_build
+                                    if self.tracer is not None else None):
+                return self._serve_continuous(requests, prompts)
+
+    def _trace_build(self, fun_name: str, seconds: float) -> None:
+        """A program built inside a traced serve: which step recompiled."""
+        self.tracer.event("program_built", tid="engine", fun_name=fun_name,
+                          compile_s=seconds)
 
     def export_trace(self, path: str, fmt: str = "chrome") -> None:
         """Write the last serve's lifecycle trace (requires
@@ -624,8 +639,9 @@ class Server:
         reg = self.metrics
         reg.begin_serve()
         tr = self.tracer
-        _annot = (tele_mod.annotation if self._tele.jax_profiler
-                  else (lambda _n: contextlib.nullcontext()))
+        _annot = self._annot
+        spans = tele_mod.StepSpans(_annot)
+        builds0 = (self._builds.n, self._builds.seconds)
         ccfg = scfg.kv_compress
         # the cache LAYOUT (clustered leaves + tail ring geometry) is
         # distinct from the retention policy served on top of it: ccfg ⇒
@@ -787,6 +803,11 @@ class Server:
         toks: Dict[int, List[int]] = {}
         pre_ms: Dict[int, float] = {}
         token_t: Dict[int, List[float]] = {}
+        # queue waits: when each request first got a slot, and when the
+        # launch carrying its first prompt chunk (or its blocking
+        # prefill) was dispatched
+        slot_t: Dict[int, float] = {}
+        launch_t: Dict[int, float] = {}
         # tracer tenancy bookkeeping: one "run" span per (slot, tenancy)
         # segment — admit/resume opens it, finish/shed/preempt closes it.
         # Token deltas across a uid's segments sum to its final count, so
@@ -827,6 +848,9 @@ class Server:
         rows_launched = 0
         pad_toks = useful_toks = 0
         n_chunks = n_absorbs = n_compacts = n_bad_logits = 0
+        # compaction work: slot rows launched, slots due, due slots whose
+        # frontier advanced, streams whose next gap holds the pass
+        compact_rows = compact_due = compact_folded = compact_gaps = 0
         # compaction cadence is per-slot decode progress, not engine
         # steps: a slot's ring only advances when that slot decodes, so
         # chunk-feed steps for OTHER slots must not inflate the schedule
@@ -1005,6 +1029,7 @@ class Server:
                             * tail_bpt + rec_state_b, j))
             return out
 
+        @tele_mod.spanned(_annot, "sched_preempt")
         def preempt(j):
             """Swap slot ``j`` out to host memory: gather its slot
             snapshot (clustered summaries + any recurrent state — the
@@ -1046,6 +1071,7 @@ class Server:
             since_tok[j] = 0
             return rec
 
+        @tele_mod.spanned(_annot, "sched_resume")
         def resume_swapped(j, rec) -> bool:
             """Re-admit a parked request mid-stream into slot ``j``
             (possibly a different slot/shard than it was preempted from
@@ -1288,6 +1314,7 @@ class Server:
                 # state feeds straight into the next step (on a prefix hit
                 # the restore overwrites all of this state instead)
                 cache = self._reset_slot(cache, jnp.int32(phys(j)))
+            slot_t.setdefault(uid, time.perf_counter())
             if tr is not None:
                 tr_open(j, uid, time.perf_counter(), "admit",
                         p0=int(fed[j]))
@@ -1326,6 +1353,8 @@ class Server:
             padded = np.zeros((1, bkt), np.int32)
             padded[0, :plen] = p
             t0 = time.perf_counter()
+            slot_t.setdefault(uid, t0)
+            launch_t.setdefault(uid, t0)
             logits1, c1 = self._prefill(self.params, jnp.asarray(padded),
                                         jnp.int32(plen - 1))
             nxt1, bad = _greedy(logits1)
@@ -1382,6 +1411,8 @@ class Server:
 
         idle_retries = stall_retries = 0
         while True:
+            spans.step()
+            spans.phase("sched_admit")
             # ---- admission ------------------------------------------------
             # next slot: the emptiest data shard's lowest free index
             # (recomputed per admission so a burst spreads across shards
@@ -1548,6 +1579,7 @@ class Server:
             bp = max(shards, 1) * bucket
 
             # ---- chunked admission: pre-step absorb (make ring room) ------
+            spans.phase("kv_absorb")
             step_chunks = {}            # logical j -> chunk len this step
             if chunk:
                 for j in np.nonzero(admitting)[0]:
@@ -1602,6 +1634,7 @@ class Server:
                 # mid-list exhaustion (or by a slot that then stalls)
                 # still get their payload copies below — the table
                 # already points at the fresh blocks
+                spans.phase("pool_ensure")
                 cow_pairs = []
                 for j in range(n):
                     if admitting[j] and j in step_chunks:
@@ -1646,6 +1679,7 @@ class Server:
                             "refresh_every")
                     continue
                 stall_retries = 0
+                spans.phase("engine_pack")
                 rows_by_shard = [[] for _ in range(max(shards, 1))]
                 for j in range(n):
                     s = shard_of(j)
@@ -1686,14 +1720,18 @@ class Server:
                         rtw[base + i] = tw_
                         rcidx[base + i] = ci
                         last_row[j] = base + i
+                spans.phase("pool_table")
                 bt_dev = bt_device()
+                spans.phase("decode_packed")
                 t0 = time.perf_counter()
-                with _annot("decode_packed"):
-                    logits, cache = self._decode_packed(
-                        self.params, cache, jnp.asarray(tokp),
-                        jnp.asarray(rslot), jnp.asarray(rpos),
-                        jnp.asarray(rtw),
-                        jnp.asarray(rcidx), bt_dev, width)
+                for j in step_chunks:
+                    launch_t.setdefault(slot_uid[j], t0)
+                logits, cache = self._decode_packed(
+                    self.params, cache, jnp.asarray(tokp),
+                    jnp.asarray(rslot), jnp.asarray(rpos),
+                    jnp.asarray(rtw),
+                    jnp.asarray(rcidx), bt_dev, width)
+                spans.phase("engine_readback")
                 nxt, bad = _greedy(logits)
                 nxt_of = lambda jj: nxt[last_row[jj]]      # noqa: E731
                 # launch_rows_frac / launch_bucket_mean stay SLOT
@@ -1702,6 +1740,7 @@ class Server:
                 # / launch_ragged_frac via compute_rows
                 rows_step, compute_rows = bp, np_rows
             else:
+                spans.phase("engine_pack")
                 tok = np.zeros((bp, width), np.int32)
                 t_vec = np.zeros(bp, np.int32)
                 cl_vec = np.ones(bp, np.int32)
@@ -1720,21 +1759,25 @@ class Server:
                         t_vec[pj] = pos[j]
 
                 t0 = time.perf_counter()
+                for j in step_chunks:
+                    launch_t.setdefault(slot_uid[j], t0)
                 if mixed:
-                    with _annot("mixed_step"):
-                        logits, cache = self._mixed(self.params, cache,
-                                                    jnp.asarray(tok),
-                                                    jnp.asarray(t_vec),
-                                                    jnp.asarray(cl_vec))
+                    spans.phase("mixed_step")
+                    logits, cache = self._mixed(self.params, cache,
+                                                jnp.asarray(tok),
+                                                jnp.asarray(t_vec),
+                                                jnp.asarray(cl_vec))
                 else:
-                    with _annot("decode_step"):
-                        logits, cache = self._decode(self.params, cache,
-                                                     jnp.asarray(tok),
-                                                     jnp.asarray(t_vec))
+                    spans.phase("decode_step")
+                    logits, cache = self._decode(self.params, cache,
+                                                 jnp.asarray(tok),
+                                                 jnp.asarray(t_vec))
+                spans.phase("engine_readback")
                 nxt, bad = _greedy(logits)
                 nxt_of = lambda jj: nxt[phys(jj)]          # noqa: E731
                 rows_step, compute_rows = bp, bp * width
             now = time.perf_counter()
+            spans.phase("engine_update")
             dec_s += now - t0
             decode_steps += 1
             n_bad_logits += bad
@@ -1838,18 +1881,21 @@ class Server:
                             kv_retired["frontier"] += (target_end
                                                        - fr.frontier(j))
                             t_ab0 = time.perf_counter()
-                            if pool is not None:
-                                cache = self._absorb_paged(
-                                    cache, jnp.int32(pj), jnp.int32(plen),
-                                    jnp.int32(target_end),
-                                    jnp.asarray(pool.row_for_read(j)))
-                                fr.set_frontier(j, target_end)
-                                pool.free_retired(j, plen, fr)
-                            else:
-                                cache = self._absorb(cache, jnp.int32(pj),
-                                                     jnp.int32(plen),
-                                                     jnp.int32(target_end))
-                                fr.set_frontier(j, target_end)
+                            with _annot("kv_absorb"):
+                                if pool is not None:
+                                    cache = self._absorb_paged(
+                                        cache, jnp.int32(pj),
+                                        jnp.int32(plen),
+                                        jnp.int32(target_end),
+                                        jnp.asarray(pool.row_for_read(j)))
+                                    fr.set_frontier(j, target_end)
+                                    pool.free_retired(j, plen, fr)
+                                else:
+                                    cache = self._absorb(
+                                        cache, jnp.int32(pj),
+                                        jnp.int32(plen),
+                                        jnp.int32(target_end))
+                                    fr.set_frontier(j, target_end)
                             n_absorbs += 1
                             if tr is not None:
                                 tr.span("absorb", t_ab0,
@@ -1915,6 +1961,7 @@ class Server:
             # alone, so admission timing (bursts, prefix-shared fast
             # paths, pool stalls) can never shift a neighbour's
             # compaction points and change its tokens
+            spans.phase("kv_compact")
             due = [j for j in range(n)
                    if ccfg is not None and active[j]
                    and since_tok[j] >= ccfg.refresh and idx_of(j) < bucket]
@@ -1939,14 +1986,19 @@ class Server:
                 for j in due:
                     newc = max(fr.frontier(j), fr.target(int(pos[j])))
                     kv_retired["frontier"] += newc - fr.frontier(j)
+                    compact_folded += newc > fr.frontier(j)
                     fr.set_frontier(j, newc)
                     if pool is not None:
                         pool.free_retired(j, int(pos[j]), fr)
                     since_tok[j] = 0
                 n_compacts += 1
+                compact_rows += bp
+                compact_due += len(due)
+                compact_gaps += int(active.sum())
                 if tr is not None:
                     tr.span("compact", t_c0, time.perf_counter(),
                             tid="engine", slots=[int(j) for j in due])
+            spans.end_phase()
 
             # ---- post-step priority pass -----------------------------
             # a pool-stalled slot (decode or admission) whose priority
@@ -1964,6 +2016,7 @@ class Server:
                     v = slo.pick_victim(victim_candidates(s), max(sp))
                     if v is not None:
                         preempt(v)
+        spans.close()
 
         if pcache is not None:
             if store is None:
@@ -1985,9 +2038,6 @@ class Server:
                                  max(shards, 1))
         wall = time.perf_counter() - t0_serve
         gen_total = sum(len(v) for v in toks.values())
-        # each request's first token comes from prefill; tokens/s rates
-        # only the tokens the decode loop actually produced
-        dec_tokens = gen_total - len(toks)
         dec_ms_tok = dec_s * 1e3 / max(gen_total, 1)
         ttfts = [pre_ms[u] / 1e3 for u in pre_ms]
         itls: List[float] = []
@@ -2008,8 +2058,6 @@ class Server:
                     ).add(gen_total)
         reg.gauge("decode_s", "seconds inside engine launches"
                   ).set(dec_s)
-        reg.gauge("tokens_per_s", "decode-loop tokens per launch second"
-                  ).set(dec_tokens / max(dec_s, 1e-9))
         reg.gauge("wall_s", "end-to-end serve wall seconds").set(wall)
         reg.gauge("tokens_per_s_wall", "all tokens per wall second"
                   ).set(gen_total / max(wall, 1e-9))
@@ -2045,6 +2093,28 @@ class Server:
                     ).add(n_absorbs)
         reg.counter("kv_compactions", "batched compaction passes"
                     ).add(n_compacts)
+        reg.counter("kv_compact_slot_rows",
+                    "slot rows launched into compaction passes"
+                    ).add(compact_rows)
+        reg.counter("kv_compact_slots_due",
+                    "slots due for compaction, summed over passes"
+                    ).add(compact_due)
+        reg.counter("kv_compact_slots_folded",
+                    "due slots whose coverage frontier advanced"
+                    ).add(compact_folded)
+        reg.counter("kv_compact_gaps",
+                    "streams still decoding at a compaction pass (each "
+                    "has its next inter-token gap stretched by it)"
+                    ).add(compact_gaps)
+        reg.counter("queue_slot_wait_s",
+                    "seconds from serve start to slot assignment, summed "
+                    "over requests").add(sum(t - t0_serve
+                                             for t in slot_t.values()))
+        reg.counter("queue_wait_s",
+                    "seconds from serve start to the dispatch of the "
+                    "launch carrying a request's first prompt chunk (or "
+                    "its blocking prefill), summed over requests"
+                    ).add(sum(t - t0_serve for t in launch_t.values()))
         reg.counter("logits_nonfinite",
                     "NaN/inf logit values in engine launches this serve"
                     ).add(n_bad_logits)
@@ -2151,6 +2221,20 @@ class Server:
                           ).set(1.0 - shard_busy_steps[s]
                                 / (shard_steps * per_shard)
                                 if shard_steps else 0.0)
+        b = self._builds
+        reg.counter("programs_built",
+                    "programs built this serve (compiled, or loaded from "
+                    "the persistent cache)").add(b.n - builds0[0])
+        reg.counter("program_build_s",
+                    "seconds tracing, lowering and compiling programs "
+                    "this serve").add(b.seconds - builds0[1])
+        reg.counter("programs_built_total",
+                    "programs built since the server was constructed",
+                    persist=True).set_to(b.n - self._builds0[0])
+        reg.counter("program_build_s_total",
+                    "seconds building programs since the server was "
+                    "constructed",
+                    persist=True).set_to(b.seconds - self._builds0[1])
         self.last_stats = reg.flat_view()
         if tr is not None:
             self.last_trace = tr.finish()
@@ -2474,10 +2558,12 @@ class Server:
         def leaf(node):
             stacked = node["k_cents"].ndim == 5
             ax = 1 if stacked else 0
-            sub = {k: jax.lax.dynamic_slice_in_dim(node[k], j, 1, axis=ax)
-                   for k in keys}
-            kt = self._gather_tail_rows(node["k_tail"], bt_row)
-            vt = self._gather_tail_rows(node["v_tail"], bt_row)
+            with tele_mod.scope("compact_gather"):
+                sub = {k: jax.lax.dynamic_slice_in_dim(node[k], j, 1,
+                                                       axis=ax)
+                       for k in keys}
+                kt = self._gather_tail_rows(node["k_tail"], bt_row)
+                vt = self._gather_tail_rows(node["v_tail"], bt_row)
             if stacked:
                 lyr = node["k_cents"].shape[0]
                 flat = {k: v.reshape((lyr,) + v.shape[2:])
@@ -2492,10 +2578,11 @@ class Server:
                 got = kv_compress.absorb_chunk(
                     sub, jnp.full((1,), lengths, jnp.int32),
                     jnp.full((1,), target, jnp.int32), ccfg)
-            return dict(node, **{
-                k: jax.lax.dynamic_update_slice_in_dim(
-                    node[k], got[k].astype(node[k].dtype), j, axis=ax)
-                for k in keys})
+            with tele_mod.scope("compact_write"):
+                return dict(node, **{
+                    k: jax.lax.dynamic_update_slice_in_dim(
+                        node[k], got[k].astype(node[k].dtype), j, axis=ax)
+                    for k in keys})
 
         def walk(node):
             if _is_clustered_kv(node):
@@ -2519,8 +2606,9 @@ class Server:
 
         def leaf(node):
             stacked = node["k_cents"].ndim == 5
-            kt = self._gather_tail_rows(node["k_tail"], bt)
-            vt = self._gather_tail_rows(node["v_tail"], bt)
+            with tele_mod.scope("compact_gather"):
+                kt = self._gather_tail_rows(node["k_tail"], bt)
+                vt = self._gather_tail_rows(node["v_tail"], bt)
             if stacked:
                 lyr, b = node["k_cents"].shape[:2]
                 flat = {k: node[k].reshape((lyr * b,) + node[k].shape[2:])
@@ -2538,8 +2626,9 @@ class Server:
                 dense["k_tail"], dense["v_tail"] = kt, vt
                 ln = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (b,))
                 got = kv_compress.recompact_clustered(dense, ln, ccfg)
-            return dict(node,
-                        **{k: got[k].astype(node[k].dtype) for k in keys})
+            with tele_mod.scope("compact_write"):
+                return dict(node, **{k: got[k].astype(node[k].dtype)
+                                     for k in keys})
 
         def walk(node):
             if _is_clustered_kv(node):

@@ -1,6 +1,7 @@
 """Serving telemetry: typed metrics registry, request-lifecycle tracer, exporters.
 
-Three layers, all host-side (nothing here runs inside jit):
+Three layers, all host-side (nothing here runs inside jit), and the names
+the profiler sees:
 
 * :class:`MetricsRegistry` — named counters / gauges / histograms that the
   engine, scheduler, pool, and template store register into instead of poking
@@ -22,6 +23,12 @@ Three layers, all host-side (nothing here runs inside jit):
   :func:`validate_trace` / :func:`validate_chrome_file` schema checks used by
   tests and CI.
 
+* Profiler names — ``TRACE_NAMES``, the one table of the named scopes the
+  jitted programs carry (:func:`scope`, metadata only) and of the host spans
+  the engine records on the ``jax.profiler`` clock (:func:`annotation`,
+  :class:`StepSpans`), plus :func:`program_builds`, the process-wide count
+  of programs built.
+
 Event schema (internal form)::
 
     {"name": str, "ph": "i" | "X", "ts": float_us, "dur": float_us (X only),
@@ -33,6 +40,8 @@ Event schema (internal form)::
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import json
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -47,8 +56,8 @@ class TelemetryConfig:
     """Per-server telemetry switches.
 
     trace:        record lifecycle + engine-step events (host-side only).
-    jax_profiler: wrap jitted launches in ``jax.profiler`` annotations so
-                  device profiles line up with the host timeline.
+    jax_profiler: record the engine's host spans (``TRACE_NAMES``) as
+                  ``jax.profiler`` annotations, on the device trace's clock.
     max_events:   tracer ring cap; events past it are counted as dropped.
     """
 
@@ -353,7 +362,28 @@ def reference_doc() -> str:
         "`<S>` ranges over data shards on a mesh; "
         "`template_cluster<C>_*` gauges appear per online traffic "
         "cluster when a template store is configured.\n\n"
-        + reference_registry().reference_table() + "\n")
+        + reference_registry().reference_table() + "\n\n"
+        + trace_names_doc())
+
+
+def trace_names_doc() -> str:
+    """The ``TRACE_NAMES`` section of ``docs/metrics.md``."""
+    lines = [
+        "## Profiler scopes and host spans\n",
+        "A *scope* names device work inside a jitted program: it is in the "
+        "name stack of every op it covers, in the program's HLO metadata "
+        "and in a device trace.  A *span* names host work on the "
+        "profiler's clock, so each idle gap of the device lines up with "
+        "what the engine was doing.  To capture both, serve with "
+        "`ServerConfig(telemetry=TelemetryConfig(jax_profiler=True))` "
+        "between `jax.profiler.start_trace(dir)` and "
+        "`jax.profiler.stop_trace()`; spans cost nothing while "
+        "`jax_profiler` is off (the default), scopes cost nothing at run "
+        "time.\n",
+        "| name | kind | what it covers |", "|---|---|---|"]
+    for name, (kind, text) in TRACE_NAMES.items():
+        lines.append(f"| `{name}` | {kind} | {text} |")
+    return "\n".join(lines) + "\n"
 
 
 # ---------------------------------------------------------------------------
@@ -548,7 +578,13 @@ def validate_trace(
 
 
 def phase_breakdown(events: Sequence[Dict[str, Any]]) -> Dict[str, float]:
-    """Per-phase wall-time breakdown (milliseconds) from a trace."""
+    """Per-phase wall-time breakdown (milliseconds) from a trace.
+
+    Launches are asynchronous: an ``engine_step`` span ends when its tokens
+    are read back, so it holds the device time of every program queued
+    before it (a compaction or absorb dispatched in the previous step
+    included).  The ``compact`` and ``absorb`` spans time only their
+    dispatch and mark when each ran; they are not phases here."""
     out: Dict[str, float] = {}
     for e in events:
         if e["ph"] != "X":
@@ -557,7 +593,7 @@ def phase_breakdown(events: Sequence[Dict[str, Any]]) -> Dict[str, float]:
         if e["name"] == "engine_step":
             kind = e["args"].get("kind", "decode")
             key = f"phase_{kind}_ms"
-        elif e["name"] in ("compact", "absorb", "swap_out", "resume", "prefill"):
+        elif e["name"] in ("swap_out", "resume", "prefill"):
             key = f"phase_{e['name']}_ms"
         else:
             continue
@@ -656,7 +692,10 @@ def write_chrome_trace(
                     "args": {"sort_index": tnum},
                 }
             )
-    other: Dict[str, Any] = {"schema": TRACE_SCHEMA, "n_shards": int(n_shards)}
+    # ts 0 is the start of the serve: the ``serve`` host span on the
+    # profiler clock starts there too
+    other: Dict[str, Any] = {"schema": TRACE_SCHEMA, "n_shards": int(n_shards),
+                             "ts_origin": "serve"}
     if stats is not None:
         other["last_stats"] = {k: float(v) for k, v in stats.items()}
     with open(path, "w") as f:
@@ -737,8 +776,73 @@ def validate_jsonl_file(path: str, reconcile: bool = True) -> List[str]:
 
 
 # ---------------------------------------------------------------------------
-# jax.profiler integration
+# jax.profiler integration: device scopes, host spans, program builds
 # ---------------------------------------------------------------------------
+
+#: Every name the engine gives its work in a profiler trace: ``scope``
+#: names device work inside a jitted program (``jax.named_scope``: it lands
+#: in the name stack of every op it covers, costs nothing at run time);
+#: ``span`` names host work on the profiler clock
+#: (``jax.profiler.TraceAnnotation``, recorded only with
+#: ``TelemetryConfig(jax_profiler=True)``).  :func:`scope` and
+#: :func:`annotation` refuse a name that is not listed here.
+TRACE_NAMES: Dict[str, Tuple[str, str]] = {
+    "compact_gather": ("scope", "compaction and absorb: tail blocks gathered "
+                       "through the block table, points (centroids plus "
+                       "aged ring entries) and their weights assembled"),
+    "kmedians_assign": ("scope", "k-medians: every point assigned to its "
+                        "nearest centroid"),
+    "kmedians_median": ("scope", "k-medians: weighted bit-serial median of "
+                        "each cluster"),
+    "kmedians_reseed": ("scope", "absorb: farthest-point re-seeding of dead "
+                        "centroid rows"),
+    "compact_write": ("scope", "compaction and absorb: centroids, counts "
+                      "and cov written back"),
+    "kv_pool_write": ("scope", "packed step: each row's K/V scattered into "
+                      "its pool block"),
+    "paged_attention": ("scope", "packed step: the paged clustered-decode "
+                        "kernel"),
+    "mlp": ("scope", "feed-forward block of a sublayer (norm, MLP or MoE, "
+            "residual)"),
+    "lm_head": ("scope", "final norm and vocabulary projection"),
+    "serve": ("span", "one `Server.serve` call; the lifecycle trace's ts 0 "
+              "is its start"),
+    "engine_step": ("span", "one iteration of the engine loop, holding the "
+                    "spans below"),
+    "sched_admit": ("span", "admission: resumes, slot choice, admission "
+                    "starts or blocking prefills, idle-engine reclaim"),
+    "sched_preempt": ("span", "one slot swapped out to host memory"),
+    "sched_resume": ("span", "one parked request re-admitted"),
+    "kv_absorb": ("span", "absorb dispatches ahead of a prompt chunk or "
+                  "after its final chunk"),
+    "pool_ensure": ("span", "blocks made writable for the step's ring "
+                    "writes, with the copy-on-write dispatch"),
+    "engine_pack": ("span", "the launch's rows built on the host"),
+    "pool_table": ("span", "block table uploaded when the allocator "
+                   "changed it"),
+    "decode_packed": ("span", "dispatch of the packed step (paged engine)"),
+    "mixed_step": ("span", "dispatch of a mixed prefill+decode launch "
+                   "(dense engine)"),
+    "decode_step": ("span", "dispatch of a decode launch (dense engine)"),
+    "engine_readback": ("span", "greedy tokens read back: waits for the "
+                        "launch and every program queued before it"),
+    "engine_update": ("span", "host bookkeeping of the launch's tokens, "
+                      "prefix registration, finishes"),
+    "kv_compact": ("span", "due-slot scan and compaction dispatch"),
+}
+
+
+def _checked(name: str, kind: str) -> str:
+    if TRACE_NAMES.get(name, ("",))[0] != kind:
+        raise KeyError(f"{name!r} is not a {kind} name in TRACE_NAMES")
+    return name
+
+
+def scope(name: str):
+    """``jax.named_scope`` for device work named in ``TRACE_NAMES``."""
+    import jax
+
+    return jax.named_scope(_checked(name, "scope"))
 
 
 def annotation(name: str):
@@ -746,7 +850,125 @@ def annotation(name: str):
     own trace, on the same clock as the device events."""
     import jax.profiler
 
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(_checked(name, "span"))
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def no_annotation(name: str):
+    """Stand-in for :func:`annotation` while host spans are off."""
+    return _NO_SPAN
+
+
+def spanned(annot, name: str):
+    """Decorator: run the function inside the host span ``annot(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with annot(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
+class StepSpans:
+    """The engine loop's host spans: :meth:`step` opens an ``engine_step``
+    span and :meth:`phase` one phase span inside it, each closing the span
+    it follows, so an iteration left by ``continue`` or ``break`` needs no
+    exit of its own; :meth:`close` ends whatever is open."""
+
+    def __init__(self, annot):
+        self._annot = annot
+        self._open: List[Any] = []      # [engine_step, current phase]
+
+    def _enter(self, name: str) -> None:
+        cm = self._annot(name)
+        cm.__enter__()
+        self._open.append(cm)
+
+    def step(self) -> None:
+        self.close()
+        self._enter("engine_step")
+
+    def phase(self, name: str) -> None:
+        self.end_phase()
+        self._enter(name)
+
+    def end_phase(self) -> None:
+        if len(self._open) > 1:
+            self._open.pop().__exit__(None, None, None)
+
+    def close(self) -> None:
+        while self._open:
+            self._open.pop().__exit__(None, None, None)
+
+
+#: ``jax.monitoring`` duration events that make up building one program:
+#: tracing to a jaxpr, lowering to MLIR, and the backend compile (JAX 0.9
+#: fires the last around ``compile_or_get_cached``, so a program loaded
+#: from the persistent cache fires it too).
+BUILD_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                "/jax/core/compile/backend_compile_duration")
+
+
+class ProgramBuilds:
+    """Programs built in this process, fed by ``jax.monitoring``
+    listeners.  ``n`` counts one program per backend-compile event,
+    compiled or loaded; ``seconds`` sums the durations of the outermost
+    build events (a jit traced inside another's trace counts once, inside
+    the outer one).  ``watch`` hands each program's name and compile
+    seconds to a callback for the length of a ``with`` block."""
+
+    def __init__(self) -> None:
+        self.n = 0
+        self.seconds = 0.0
+        self._depth = 0
+        self._watchers: List[Any] = []
+
+    def _on_start(self, event, _value, **_kw) -> None:
+        if event in BUILD_EVENTS:
+            self._depth += 1
+
+    def _on_duration(self, event, secs, **kw) -> None:
+        if event not in BUILD_EVENTS:
+            return
+        self._depth = max(self._depth - 1, 0)
+        if self._depth == 0:
+            self.seconds += float(secs)
+        if event == BUILD_EVENTS[2]:
+            self.n += 1
+            for cb in self._watchers:
+                cb(str(kw.get("fun_name", "")), float(secs))
+
+    @contextlib.contextmanager
+    def watch(self, callback):
+        if callback is None:
+            yield
+            return
+        self._watchers.append(callback)
+        try:
+            yield
+        finally:
+            self._watchers.remove(callback)
+
+
+_BUILDS: Optional[ProgramBuilds] = None
+
+
+def program_builds() -> ProgramBuilds:
+    """The process-wide :class:`ProgramBuilds`; its listeners are
+    registered with ``jax.monitoring`` on the first call only."""
+    global _BUILDS
+    if _BUILDS is None:
+        import jax.monitoring as mon
+
+        _BUILDS = ProgramBuilds()
+        # the build events record their start time as a scalar on entry
+        mon.register_scalar_listener(_BUILDS._on_start)
+        mon.register_event_duration_secs_listener(_BUILDS._on_duration)
+    return _BUILDS
 
 
 # ---------------------------------------------------------------------------

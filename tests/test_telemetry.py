@@ -9,10 +9,15 @@ schedule-invisible (greedy tokens bit-identical with tracing on vs off,
 including under preemption/swap/resume pressure), the emitted trace must
 satisfy every schema invariant and reconcile against ``last_stats``, and
 dynamic per-serve keys from one serve must never leak into the next
-serve's stats (the stale-``last_stats``-keys regression).
+serve's stats (the stale-``last_stats``-keys regression).  The engine's
+counters of compaction work, queue waits and program builds follow a
+schedule derived by hand, and every named scope of ``TRACE_NAMES``
+reaches the metadata of the lowered compaction, absorb and packed-step
+programs.
 """
 
 import json
+import re
 
 import numpy as np
 import pytest
@@ -23,12 +28,14 @@ from repro.core import kv_compress
 from repro.core.request_cluster import Request
 from repro.models import transformer as tfm
 from repro.models.config import ModelConfig
-from repro.runtime.kv_pool import PagedKVConfig
+from repro.runtime.kv_pool import BlockPool, PagedKVConfig
 from repro.runtime.scheduler import SLOConfig
 from repro.runtime.server import Server, ServerConfig
-from repro.runtime.telemetry import (TRACE_SCHEMA, MetricsRegistry,
-                                     TelemetryConfig, Tracer,
-                                     events_from_chrome, phase_breakdown,
+from repro.runtime.telemetry import (BUILD_EVENTS, TRACE_NAMES, TRACE_SCHEMA,
+                                     MetricsRegistry, ProgramBuilds,
+                                     StepSpans, TelemetryConfig, Tracer,
+                                     annotation, events_from_chrome,
+                                     phase_breakdown, scope, spanned,
                                      validate_chrome_file,
                                      validate_jsonl_file, validate_trace,
                                      write_chrome_trace, write_jsonl)
@@ -202,12 +209,16 @@ class TestValidateTrace:
             evs, totals={**totals, "decode_steps": 2.0}))
 
     def test_phase_breakdown(self):
+        # compact/absorb spans time a dispatch, not a phase: the device
+        # time of a compaction lands in the next engine_step
         ph = phase_breakdown([
             _sp("engine_step", 0.0, 1000.0, kind="decode"),
             _sp("engine_step", 2000.0, 3000.0, kind="mixed"),
-            _sp("compact", 6000.0, 500.0)])
-        assert ph == {"phase_compact_ms": 0.5, "phase_decode_ms": 1.0,
-                      "phase_mixed_ms": 3.0}
+            _sp("compact", 6000.0, 500.0),
+            _sp("absorb", 7000.0, 200.0),
+            _sp("swap_out", 8000.0, 250.0, uid=1, tid="slot0")])
+        assert ph == {"phase_decode_ms": 1.0, "phase_mixed_ms": 3.0,
+                      "phase_swap_out_ms": 0.25}
 
 
 # ---------------------------------------------------------------------------
@@ -229,6 +240,7 @@ class TestExporters:
                            stats={"gen_tokens": 5.0})
         obj = json.load(open(path))
         assert obj["otherData"]["schema"] == TRACE_SCHEMA
+        assert obj["otherData"]["ts_origin"] == "serve"
         # metadata names every (pid, tid) track for Perfetto
         meta = {(e["pid"], e["name"]) for e in obj["traceEvents"]
                 if e["ph"] == "M"}
@@ -416,3 +428,227 @@ class TestMetricsReference:
         for key in ("kv_retired_recurrent", "state_bytes_ring",
                     "state_bytes_recurrent", "sched_swap_bytes"):
             assert key in names, key
+
+
+# ---------------------------------------------------------------------------
+# engine: compaction work, queue waits and program builds are counted
+# ---------------------------------------------------------------------------
+
+
+def _two_streams(keep_recent, trace=False):
+    """Two slots, two requests of an 8-token prompt and 10 tokens each,
+    compaction every 4 decode tokens."""
+    ccfg = kv_compress.KVCompressConfig(n_clusters=8, iters=4,
+                                        keep_recent=keep_recent,
+                                        refresh_every=4)
+    scfg = ServerConfig(batch_size=2, max_seq=64, kv_compress=ccfg,
+                        prefill_chunk=8, paged=PagedKVConfig(block_size=4),
+                        use_clustered_batching=False,
+                        telemetry=TelemetryConfig(trace=trace))
+    rng = np.random.default_rng(0)
+    prompts = {u: rng.integers(0, 64, size=(8,)).astype(np.int32)
+               for u in range(2)}
+    return scfg, [Request(u, 8, 10) for u in range(2)], prompts
+
+
+class TestEngineCounters:
+
+    @pytest.mark.parametrize("keep_recent,folded", [(32, 0), (16, 2)],
+                             ids=["no_fold", "fold"])
+    def test_compaction_and_queue_counters(self, params, keep_recent,
+                                           folded):
+        """The schedule, by hand: one admitting slot per shard, so A's
+        one chunk goes in launch 1 and B's in launch 2; A decodes its
+        tokens at launches 1-10, B at 2-11.  A slot is due after 4 of its
+        own decode tokens: A at launches 5 and 9, B at 6 and 10, four
+        passes of 2 slot rows.  Streams still decoding at the passes: 2,
+        2, 2 and 1 (A finished at launch 10).  The frontier target is
+        pos - keep_recent + 4 and pos is 12 at a stream's first pass, 16
+        at its second: a ring of 16 folds the second passes only, a ring
+        of 32 folds none."""
+        scfg, reqs, prompts = _two_streams(keep_recent)
+        srv = Server(TINY, scfg, params)
+        outs = srv.serve(reqs, prompts)
+        st = srv.last_stats
+        assert [len(o.tokens) for o in outs] == [10, 10]
+        assert st["decode_steps"] == 11.0
+        assert st["kv_compactions"] == 4.0
+        assert st["kv_compact_slot_rows"] == 8.0
+        assert st["kv_compact_slots_due"] == 4.0
+        assert st["kv_compact_slots_folded"] == float(folded)
+        assert st["kv_compact_gaps"] == 7.0
+        assert st["kv_retired_frontier"] == 4.0 * folded
+        assert 0.0 < st["queue_slot_wait_s"] <= st["queue_wait_s"]
+        ttft_s = sum(o.prefill_ms for o in outs) / 1e3
+        assert st["queue_wait_s"] <= ttft_s
+
+    def test_programs_built_counts_new_programs_only(self, params):
+        scfg, reqs, prompts = _two_streams(16, trace=True)
+        srv = Server(TINY, scfg, params)
+        srv.serve(reqs, prompts)
+        first = dict(srv.last_stats)
+        built = [e for e in srv.last_trace if e["name"] == "program_built"]
+        assert first["programs_built"] >= 1.0
+        assert first["program_build_s"] > 0.0
+        assert len(built) == first["programs_built"]
+        assert any("_packed_fn" in e["args"]["fun_name"] for e in built)
+        assert validate_trace(srv.last_trace, totals=first) == []
+
+        srv.serve(reqs, prompts)                   # same shapes again
+        second = srv.last_stats
+        assert second["programs_built"] == 0.0
+        assert not [e for e in srv.last_trace
+                    if e["name"] == "program_built"]
+        assert (second["programs_built_total"]
+                == first["programs_built_total"] >= first["programs_built"])
+        assert (second["program_build_s_total"]
+                >= first["program_build_s_total"]
+                >= first["program_build_s"])
+
+
+class TestProgramBuilds:
+
+    def test_nested_traces_count_once_and_watchers_see_compiles(self):
+        trace, lower, compile_ = BUILD_EVENTS
+        b = ProgramBuilds()
+        seen = []
+        b._on_start(trace, 0.0, fun_name="outer")
+        b._on_start(trace, 0.0, fun_name="inner")
+        b._on_duration(trace, 1.0, fun_name="inner")
+        b._on_duration(trace, 3.0, fun_name="outer")
+        b._on_duration("/jax/other_duration", 7.0)
+        with b.watch(lambda name, secs: seen.append((name, secs))):
+            for ev, secs in ((lower, 0.5), (compile_, 2.0)):
+                b._on_start(ev, 0.0, fun_name="jit(f)")
+                b._on_duration(ev, secs, fun_name="jit(f)")
+        b._on_start(compile_, 0.0, fun_name="jit(g)")
+        b._on_duration(compile_, 1.0, fun_name="jit(g)")
+        assert b.n == 2
+        assert b.seconds == 3.0 + 0.5 + 2.0 + 1.0
+        assert seen == [("jit(f)", 2.0)]
+
+
+# ---------------------------------------------------------------------------
+# profiler names: one table, scopes in the program metadata
+# ---------------------------------------------------------------------------
+
+
+def _in_name_stack(text, name):
+    """Whether an op location of lowered program text names ``name`` as
+    one level of its name stack (a transform wraps the level it starts
+    at: ``vmap(name)/...``)."""
+    return re.search(r'loc\("(?:[^"]*[/(])?' + re.escape(name) + '[/)]',
+                     text) is not None
+
+
+class TestProfilerNames:
+
+    def test_names_are_checked_against_the_table(self):
+        with pytest.raises(KeyError):
+            scope("no_such_scope")
+        with pytest.raises(KeyError):
+            annotation("kmedians_median")          # a scope, not a span
+        assert {k for k, _ in TRACE_NAMES.values()} == {"scope", "span"}
+
+    def test_step_spans_nest_and_close(self):
+        log = []
+
+        class Rec:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("+", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("-", self.name))
+
+        sp = StepSpans(Rec)
+        sp.step()
+        sp.phase("sched_admit")
+        sp.phase("kv_compact")
+        sp.step()
+        sp.phase("engine_pack")
+        sp.end_phase()
+        sp.close()
+        assert log == [("+", "engine_step"), ("+", "sched_admit"),
+                       ("-", "sched_admit"), ("+", "kv_compact"),
+                       ("-", "kv_compact"), ("-", "engine_step"),
+                       ("+", "engine_step"), ("+", "engine_pack"),
+                       ("-", "engine_pack"), ("-", "engine_step")]
+
+        @spanned(Rec, "sched_preempt")
+        def work(x):
+            log.append(("work", x))
+            return x + 1
+
+        del log[:]
+        assert work(1) == 2
+        assert log == [("+", "sched_preempt"), ("work", 1),
+                       ("-", "sched_preempt")]
+
+    @pytest.fixture(scope="class")
+    def lowered(self, params):
+        """Metadata text of the paged engine's compaction, absorb and
+        packed-step programs, lowered from shapes."""
+        scfg, _, _ = _two_streams(16)
+        srv = Server(TINY, scfg, params)
+        n, r, c = scfg.batch_size, 16, 8
+        pool = BlockPool(n, r, scfg.paged, n_shards=1, slots_per_shard=n,
+                         full_tail_resident=True)
+        cache = jax.eval_shape(lambda: tfm.init_cache(
+            TINY, n, scfg.max_seq, kv_mode="clustered", kv_clusters=c,
+            kv_tail=r, kv_pool_blocks=pool.n_blocks,
+            kv_block_size=scfg.paged.block_size))
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, np.int32)
+
+        t = pool.blocks_per_slot
+        lows = {
+            "compact": srv._compact_paged.lower(cache, i32(n), i32(n, t)),
+            "absorb": srv._absorb_paged.lower(cache, i32(), i32(), i32(),
+                                              i32(t)),
+            "packed": srv._decode_packed.lower(
+                params, cache, *(i32(4) for _ in range(5)), i32(n, t), 1),
+        }
+        return {k: low.as_text(debug_info=True) for k, low in lows.items()}
+
+    @pytest.mark.parametrize("program,scopes", [
+        ("compact", ("compact_gather", "kmedians_assign", "kmedians_median",
+                     "compact_write")),
+        ("absorb", ("compact_gather", "kmedians_assign", "kmedians_median",
+                    "kmedians_reseed", "compact_write")),
+        ("packed", ("kv_pool_write", "paged_attention", "mlp", "lm_head")),
+    ])
+    def test_scopes_reach_program_metadata(self, lowered, program, scopes):
+        for name in scopes:
+            assert _in_name_stack(lowered[program], name), (program, name)
+
+    def test_every_scope_is_in_a_program(self, lowered):
+        text = "".join(lowered.values())
+        assert [name for name, (kind, _) in TRACE_NAMES.items()
+                if kind == "scope" and not _in_name_stack(text, name)] == []
+
+    # the needles bench/metrics matches in device-trace program names
+    # (kv_manage_share, step_device_ms) and op names
+    # (paged_decode_roofline): a rename silences a metric
+    @pytest.mark.parametrize("attr,needle", [
+        ("_decode_packed", "_packed_fn"), ("_compact_paged", "compact"),
+        ("_absorb_paged", "absorb"), ("_reset_slot", "reset_slot"),
+        ("_write_slot_paged", "write_slot"), ("_cow", "cow"),
+        ("_swap_in", "swap_in"), ("_swap_out", "swap_out")])
+    def test_program_names_keep_the_benchmark_needles(self, params, attr,
+                                                      needle):
+        scfg, _, _ = _two_streams(16)
+        assert needle in getattr(Server(TINY, scfg, params), attr).__name__
+
+    def test_module_and_kernel_names_in_lowered_programs(self, lowered):
+        def module(text):
+            return next(ln for ln in text.splitlines()
+                        if ln.startswith("module @"))
+
+        assert "_packed_fn" in module(lowered["packed"])
+        assert "compact" in module(lowered["compact"])
+        assert "absorb" in module(lowered["absorb"])
+        assert "paged_clustered_decode" in lowered["packed"]
