@@ -153,20 +153,19 @@ def recurrent_leaf_stacked(node) -> bool:
 
 
 def _walk_leaves(cache, pred):
-    out = []
-
-    def walk(node):
+    # an explicit stack, not a recursive closure: a closure that calls
+    # itself is a reference cycle, which would keep the leaves (and their
+    # device buffers) alive after the call until the cyclic collector runs
+    out, stack = [], [cache]
+    while stack:
+        node = stack.pop()
         if isinstance(node, dict):
             if pred(node):
                 out.append(node)
-                return
-            for v in node.values():
-                walk(v)
+            else:
+                stack.extend(reversed(list(node.values())))
         elif isinstance(node, (list, tuple)):
-            for v in node:
-                walk(v)
-
-    walk(cache)
+            stack.extend(reversed(node))
     return out
 
 

@@ -236,6 +236,15 @@ def _greedy(logits):
     return np.asarray(nxt).astype(np.int32), int(bad)
 
 
+def _frontier_advances(due, pos, fr) -> bool:
+    """Whether a compaction pass over the ``due`` slots folds anything:
+    some due slot's coverage frontier moves on the host mirror (the
+    formula ``recompact_clustered`` applies on the device).  A pass in
+    which none moves hands every row back bit-identical
+    (``changed = new_cov > cov``), so the engine skips its launch."""
+    return any(fr.target(int(pos[j])) > fr.frontier(j) for j in due)
+
+
 def _percentile_ms(vals: List[float], q: float) -> float:
     if not vals:
         return 0.0
@@ -849,8 +858,10 @@ class Server:
         pad_toks = useful_toks = 0
         n_chunks = n_absorbs = n_compacts = n_bad_logits = 0
         # compaction work: slot rows launched, slots due, due slots whose
-        # frontier advanced, streams whose next gap holds the pass
+        # frontier advanced, streams whose next gap holds a launched
+        # pass, passes whose launch was skipped (nothing to fold)
         compact_rows = compact_due = compact_folded = compact_gaps = 0
+        compact_skipped = 0
         # compaction cadence is per-slot decode progress, not engine
         # steps: a slot's ring only advances when that slot decodes, so
         # chunk-feed steps for OTHER slots must not inflate the schedule
@@ -1960,26 +1971,36 @@ class Server:
             # slot's compaction schedule a function of its own stream
             # alone, so admission timing (bursts, prefix-shared fast
             # paths, pool stalls) can never shift a neighbour's
-            # compaction points and change its tokens
+            # compaction points and change its tokens.  A pass in which
+            # no due slot's frontier advances would return every row
+            # unchanged: its launch is skipped, its bookkeeping kept
             spans.phase("kv_compact")
             due = [j for j in range(n)
                    if ccfg is not None and active[j]
                    and since_tok[j] >= ccfg.refresh and idx_of(j) < bucket]
             if due:
-                lengths = np.zeros(bp, np.int32)
-                for j in due:
-                    lengths[phys(j)] = pos[j]
                 t_c0 = time.perf_counter()
-                if pool is not None:
-                    cache = self._compact_paged(cache, jnp.asarray(lengths),
-                                                bt_device())
+                launch = _frontier_advances(due, pos, fr)
+                if launch:
+                    lengths = np.zeros(bp, np.int32)
+                    for j in due:
+                        lengths[phys(j)] = pos[j]
+                    if pool is not None:
+                        cache = self._compact_paged(
+                            cache, jnp.asarray(lengths), bt_device())
+                    else:
+                        cache = self.compact_kv(cache, lengths, ccfg)
+                        if self._rules is not None:
+                            # eviction/compaction rebuilt the clustered
+                            # leaves outside the constrained decode jit —
+                            # put them back on their mesh layout before
+                            # the next step
+                            cache = shard_cache(cache, self._rules)
+                    n_compacts += 1
+                    compact_rows += bp
+                    compact_gaps += int(active.sum())
                 else:
-                    cache = self.compact_kv(cache, lengths, ccfg)
-                    if self._rules is not None:
-                        # eviction/compaction rebuilt the clustered leaves
-                        # outside the constrained decode jit — put them
-                        # back on their mesh layout before the next step
-                        cache = shard_cache(cache, self._rules)
+                    compact_skipped += 1
                 # host frontier mirror (recompact_clustered's formula) —
                 # compaction is when the paged engine returns retired
                 # blocks to the pool
@@ -1991,11 +2012,8 @@ class Server:
                     if pool is not None:
                         pool.free_retired(j, int(pos[j]), fr)
                     since_tok[j] = 0
-                n_compacts += 1
-                compact_rows += bp
                 compact_due += len(due)
-                compact_gaps += int(active.sum())
-                if tr is not None:
+                if launch and tr is not None:
                     tr.span("compact", t_c0, time.perf_counter(),
                             tid="engine", slots=[int(j) for j in due])
             spans.end_phase()
@@ -2091,21 +2109,26 @@ class Server:
                     ).add(n_chunks)
         reg.counter("kv_absorbs", "streaming absorb_chunk calls"
                     ).add(n_absorbs)
-        reg.counter("kv_compactions", "batched compaction passes"
+        reg.counter("kv_compactions", "batched compaction passes launched"
                     ).add(n_compacts)
         reg.counter("kv_compact_slot_rows",
                     "slot rows launched into compaction passes"
                     ).add(compact_rows)
         reg.counter("kv_compact_slots_due",
-                    "slots due for compaction, summed over passes"
+                    "slots due for compaction, summed over passes "
+                    "(launched or skipped)"
                     ).add(compact_due)
         reg.counter("kv_compact_slots_folded",
                     "due slots whose coverage frontier advanced"
                     ).add(compact_folded)
         reg.counter("kv_compact_gaps",
-                    "streams still decoding at a compaction pass (each "
-                    "has its next inter-token gap stretched by it)"
+                    "streams still decoding at a launched compaction pass "
+                    "(each has its next inter-token gap stretched by it)"
                     ).add(compact_gaps)
+        reg.counter("kv_compact_passes_skipped",
+                    "passes with due slots whose launch was skipped: no "
+                    "due slot's coverage frontier advanced"
+                    ).add(compact_skipped)
         reg.counter("queue_slot_wait_s",
                     "seconds from serve start to slot assignment, summed "
                     "over requests").add(sum(t - t0_serve

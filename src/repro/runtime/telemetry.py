@@ -828,7 +828,9 @@ TRACE_NAMES: Dict[str, Tuple[str, str]] = {
                         "launch and every program queued before it"),
     "engine_update": ("span", "host bookkeeping of the launch's tokens, "
                       "prefix registration, finishes"),
-    "kv_compact": ("span", "due-slot scan and compaction dispatch"),
+    "kv_compact": ("span", "due-slot scan, host frontier update, and the "
+                   "compaction dispatch when some due slot's frontier "
+                   "advances"),
 }
 
 

@@ -11,6 +11,8 @@
     coverage frontier monotonically.
 """
 
+import gc
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -374,6 +376,30 @@ class TestPagedEngine:
         st = srv.last_stats
         assert st["pool_allocs"] > st["pool_blocks_peak"]
         assert st["pool_frees"] == st["pool_allocs"]      # all returned
+
+    @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+    def test_engine_cache_freed_when_serve_returns(self, pieces, paged):
+        """No device buffer of a serve outlives it by reference count
+        alone: one kept by a reference cycle lives until the cyclic
+        collector runs, and then the next serve allocates its engine
+        cache beside it (on the chip, the device memory peak grows by a
+        whole cache)."""
+        params = pieces[0]
+        reqs, prompts = self._stream()
+        srv = Server(TINY, ServerConfig(
+            batch_size=2, max_seq=96, kv_compress=self.CCFG,
+            prefill_chunk=8, paged=self.PG if paged else None), params)
+        srv.serve(reqs, prompts)                # programs built, caches set
+        gc.collect()
+        before = {id(a) for a in jax.live_arrays()}
+        gc.disable()
+        try:
+            srv.serve(reqs, prompts)
+            left = [a.shape for a in jax.live_arrays()
+                    if id(a) not in before]
+        finally:
+            gc.enable()
+        assert left == []
 
     def test_oversubscribed_pool_serves_short_streams(self, pieces):
         """A pool smaller than slots × blocks-per-slot still serves when

@@ -11,12 +11,15 @@ satisfy every schema invariant and reconcile against ``last_stats``, and
 dynamic per-serve keys from one serve must never leak into the next
 serve's stats (the stale-``last_stats``-keys regression).  The engine's
 counters of compaction work, queue waits and program builds follow a
-schedule derived by hand, and every named scope of ``TRACE_NAMES``
-reaches the metadata of the lowered compaction, absorb and packed-step
-programs.
+schedule derived by hand, a compaction pass skipped because it would fold
+nothing leaves tokens and memory state bit-identical, and every named
+scope of ``TRACE_NAMES`` reaches the metadata of the lowered compaction,
+absorb and packed-step programs.
 """
 
+import dataclasses
 import json
+import pathlib
 import re
 
 import numpy as np
@@ -24,11 +27,12 @@ import pytest
 
 import jax
 
-from repro.core import kv_compress
+from repro.core import kv_compress, layer_state
 from repro.core.request_cluster import Request
 from repro.models import transformer as tfm
 from repro.models.config import ModelConfig
 from repro.runtime.kv_pool import BlockPool, PagedKVConfig
+from repro.runtime import server as server_mod
 from repro.runtime.scheduler import SLOConfig
 from repro.runtime.server import Server, ServerConfig
 from repro.runtime.telemetry import (BUILD_EVENTS, TRACE_NAMES, TRACE_SCHEMA,
@@ -40,6 +44,8 @@ from repro.runtime.telemetry import (BUILD_EVENTS, TRACE_NAMES, TRACE_SCHEMA,
                                      validate_jsonl_file, validate_trace,
                                      write_chrome_trace, write_jsonl)
 from repro.runtime.template_store import TemplateStoreConfig
+
+from _subproc import run_sub
 
 TINY = ModelConfig(name="tiny", family="dense", n_layers=2, d_model=64,
                    n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128, vocab=64,
@@ -453,10 +459,11 @@ def _two_streams(keep_recent, trace=False):
 
 class TestEngineCounters:
 
-    @pytest.mark.parametrize("keep_recent,folded", [(32, 0), (16, 2)],
+    @pytest.mark.parametrize("keep_recent,folded,skipped,gaps",
+                             [(32, 0, 4, 0), (16, 2, 2, 3)],
                              ids=["no_fold", "fold"])
     def test_compaction_and_queue_counters(self, params, keep_recent,
-                                           folded):
+                                           folded, skipped, gaps):
         """The schedule, by hand: one admitting slot per shard, so A's
         one chunk goes in launch 1 and B's in launch 2; A decodes its
         tokens at launches 1-10, B at 2-11.  A slot is due after 4 of its
@@ -465,18 +472,23 @@ class TestEngineCounters:
         2, 2 and 1 (A finished at launch 10).  The frontier target is
         pos - keep_recent + 4 and pos is 12 at a stream's first pass, 16
         at its second: a ring of 16 folds the second passes only, a ring
-        of 32 folds none."""
+        of 32 folds none.  A pass whose due slots fold nothing is not
+        launched: with the ring of 16 the first two passes are skipped
+        and the passes at launches 9 and 10 run (2 and 1 streams still
+        decoding); with the ring of 32 all four are skipped.  Slots due
+        count every pass, launched or skipped."""
         scfg, reqs, prompts = _two_streams(keep_recent)
         srv = Server(TINY, scfg, params)
         outs = srv.serve(reqs, prompts)
         st = srv.last_stats
         assert [len(o.tokens) for o in outs] == [10, 10]
         assert st["decode_steps"] == 11.0
-        assert st["kv_compactions"] == 4.0
-        assert st["kv_compact_slot_rows"] == 8.0
+        assert st["kv_compactions"] == 4.0 - skipped
+        assert st["kv_compact_slot_rows"] == 2.0 * (4 - skipped)
         assert st["kv_compact_slots_due"] == 4.0
         assert st["kv_compact_slots_folded"] == float(folded)
-        assert st["kv_compact_gaps"] == 7.0
+        assert st["kv_compact_gaps"] == float(gaps)
+        assert st["kv_compact_passes_skipped"] == float(skipped)
         assert st["kv_retired_frontier"] == 4.0 * folded
         assert 0.0 < st["queue_slot_wait_s"] <= st["queue_wait_s"]
         ttft_s = sum(o.prefill_ms for o in outs) / 1e3
@@ -504,6 +516,92 @@ class TestEngineCounters:
         assert (second["program_build_s_total"]
                 >= first["program_build_s_total"]
                 >= first["program_build_s"])
+
+
+def _clustered_leaves(cache):
+    """Every clustered-KV leaf's centroid bank and frontier, on the host."""
+    if isinstance(cache, dict) and "k_cents" in cache:
+        return [{k: np.asarray(cache[k])
+                 for k in ("cov", "counts", "k_cents", "v_cents")}]
+    kids = (cache.values() if isinstance(cache, dict)
+            else cache if isinstance(cache, list) else ())
+    return [leaf for kid in kids for leaf in _clustered_leaves(kid)]
+
+
+def skip_matches_launch(engine):
+    """Serve ``_two_streams(16)`` (first passes fold nothing, second
+    passes fold) with every due pass launched, as before the skip rule,
+    and with the rule; check tokens, the final centroid banks and
+    frontiers, and the host frontier mirror.  ``engine`` is ``dense``,
+    ``paged`` or ``mesh`` (the dense engine on a 2x1 mesh, which needs
+    two devices)."""
+    params = tfm.init_params(jax.random.PRNGKey(0), TINY)
+    scfg, reqs, prompts = _two_streams(16)
+    if engine != "paged":
+        scfg = dataclasses.replace(scfg, paged=None)
+    if engine == "mesh":
+        from repro.launch.mesh import make_serving_mesh
+        scfg = dataclasses.replace(scfg, mesh=make_serving_mesh("2x1"))
+    rule, state_bytes = (server_mod._frontier_advances,
+                         layer_state.ring_state_bytes)
+    runs = {}
+    for name in ("launch", "skip"):
+        seen = {}
+
+        def advances(due, pos, fr, name=name, seen=seen):
+            seen["fr"] = fr
+            return name == "launch" or rule(due, pos, fr)
+
+        def final_cache(cache, n, seen=seen):
+            seen["cache"] = cache           # read once, at the serve's end
+            return state_bytes(cache, n)
+
+        server_mod._frontier_advances = advances
+        layer_state.ring_state_bytes = final_cache
+        try:
+            srv = Server(TINY, scfg, params)
+            outs = srv.serve(reqs, prompts)
+        finally:
+            server_mod._frontier_advances = rule
+            layer_state.ring_state_bytes = state_bytes
+        runs[name] = ({o.uid: o.tokens for o in outs},
+                      _clustered_leaves(seen["cache"]), seen["fr"].cov,
+                      srv.last_stats)
+    (tok_l, leaves_l, _, st_l), (tok_s, leaves_s, cov_s, st_s) = (
+        runs["launch"], runs["skip"])
+    # each stream's first pass folds nothing and its second folds; on the
+    # mesh both streams start in the first launch, so each pass holds
+    # both due slots: two passes, not four
+    passes = st_l["kv_compactions"]
+    assert passes in (2.0, 4.0) and st_l["kv_compact_passes_skipped"] == 0
+    assert st_s["kv_compactions"] == st_s["kv_compact_passes_skipped"]
+    assert st_s["kv_compactions"] + st_s["kv_compact_passes_skipped"] == passes
+    assert st_s["kv_compact_slots_folded"] == st_l["kv_compact_slots_folded"]
+    assert tok_s == tok_l
+    assert len(leaves_s) == len(leaves_l) > 0
+    for got, want in zip(leaves_s, leaves_l):
+        for key in want:
+            np.testing.assert_array_equal(got[key], want[key], err_msg=key)
+        # both slots sit at rows 0 and 1 (one shard of two slots, or two
+        # shards of one); a stacked leaf repeats the frontier per layer
+        dev = got["cov"].reshape(-1, got["cov"].shape[-1])
+        for row in dev:
+            np.testing.assert_array_equal(row, cov_s[:len(row)])
+
+
+class TestCompactionSkip:
+
+    @pytest.mark.parametrize("engine", ["dense", "paged", "mesh"])
+    def test_skipped_passes_serve_bit_identical_state(self, engine):
+        if engine != "mesh":
+            skip_matches_launch(engine)
+            return
+        run_sub(f"""
+            import sys
+            sys.path.insert(0, {str(pathlib.Path(__file__).parent)!r})
+            import test_telemetry
+            test_telemetry.skip_matches_launch("mesh")
+        """)
 
 
 class TestProgramBuilds:
