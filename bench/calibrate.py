@@ -44,12 +44,14 @@ from bench import run as harness  # noqa: E402
 CONTROLS = {"bfloat16": ("int8", "fp8"), "float32": ("bf16",)}
 
 
-def readings(srv, cfg, conf, mix, limit, seed, m, mem):
+def readings(srv, arch, conf, mix, limit, seed):
     """The program's and the controls' widest gaps on one seed, and the
     harness's verdict on each under ``limit``."""
     controls = CONTROLS[conf["serving"]["dtype"]]
+    m, mem = arch.reference_model(conf), reference.memory(conf)
     srv.params = None          # free the last seed's weights first
-    params = weights.build(cfg, seed, conf["initializer_range"])
+    params = weights.build(arch.program_config(conf), seed,
+                           conf["initializer_range"], arch)
     srv.params = params
     job = harness.serve_job(srv, *loadgen.job(mix, conf["vocab_size"], seed,
                                               0))
@@ -57,7 +59,7 @@ def readings(srv, cfg, conf, mix, limit, seed, m, mem):
     failed = sum(1 for r in job["requests"] if r[5] or len(r[3]) != r[2])
     picked = harness.sample([job], seed)
     served = [t for _, t in picked]
-    refs = [harness.reference_logits(params, p, t, mix, m, mem)
+    refs = [harness.reference_logits(arch, params, p, t, mix, m, mem)
             for p, t in picked]
     out = {"seed": seed, "job_s": job["wall_s"],
            "absorbs": s["kv_absorbs"], "compactions": s["kv_compactions"],
@@ -68,7 +70,8 @@ def readings(srv, cfg, conf, mix, limit, seed, m, mem):
                                                 limit)
     for c in controls:
         firsts = [np.asarray(harness.reference_logits(
-            params, p, t, mix, m, mem, weight_quant=c))[:len(t)].argmax(-1)
+            arch, params, p, t, mix, m, mem,
+            weight_quant=c))[:len(t)].argmax(-1)
             for p, t in picked]
         out[c] = harness.widest_gap(refs, firsts)
         out[f"{c}_mean"] = mean_gap(refs, firsts)
@@ -106,16 +109,16 @@ def main(argv=None) -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     conf = config.load_config(cell["config"])
     mix = config.load_mix(cell["traffic"])
-    cfg = config.model_config(conf)
-    m, mem = reference.from_config(conf)
+    arch = config.arch_for(conf)
+    cfg = arch.program_config(conf)
     limit = config.load_check(args.workload)["max_logit_gap"]
     srv = Server(cfg, config.server_config(conf, mix["slots"]),
                  weights.build(cfg, args.seeds[0],
-                               conf["initializer_range"]))
+                               conf["initializer_range"], arch))
     rows = []
     for seed in args.seeds:
         t0 = time.perf_counter()
-        r = readings(srv, cfg, conf, mix, limit, seed, m, mem)
+        r = readings(srv, arch, conf, mix, limit, seed)
         r["seconds"] = time.perf_counter() - t0
         rows.append(r)
         print(json.dumps(r), flush=True)

@@ -4,17 +4,23 @@ program's objects.
 Everything that belongs to one configuration, one traffic mix or one cell
 lives in a data file of its own: ``bench/configs/<config>.json``,
 ``bench/traffic/<mix>.json`` and the cell's check limits
-``bench/checks/<workload>.json``.  The harness reads them by the names
-``BENCHMARK.json`` gives, so a new cell needs new files and entries only.
+``bench/checks/<workload>.json``; what belongs to one architecture lives
+in ``bench/arch/<model_type>.py`` (its contract is in
+``bench/arch/__init__.py``).  The harness reads them by the names
+``BENCHMARK.json`` and the configuration file give, so a new cell needs
+new files and entries only.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import json
+import sys
 from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+ARCH_DIR = BENCH_DIR / "arch"
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -42,24 +48,22 @@ def load_check(workload: str) -> dict:
     return json.loads((BENCH_DIR / "checks" / f"{workload}.json").read_text())
 
 
-def model_config(conf: dict):
-    """The configuration file as the program's ``ModelConfig`` (every
-    published key it reads maps to one field)."""
-    from repro.models.config import ModelConfig
-
-    act = conf["hidden_act"]
-    if act != "silu":
-        raise ValueError(f"hidden_act {act!r}: only silu (SwiGLU) is served")
-    return ModelConfig(
-        name=conf["name"], family="dense",
-        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"], head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"], vocab=conf["vocab_size"],
-        layer_pattern="G", mlp_kind="swiglu", norm_eps=conf["rms_norm_eps"],
-        qk_norm=conf["qk_norm"], rope_theta=float(conf["rope_theta"]),
-        tie_embeddings=conf["tie_word_embeddings"],
-        dtype=conf["serving"]["dtype"]).validate()
+def arch_for(conf: dict):
+    """The architecture module of a configuration file: ``<ARCH_DIR>/<its
+    model_type>.py``, loaded once per process (its jitted reference then
+    compiles once).  Exits naming the path when there is none."""
+    path = ARCH_DIR / f"{conf.get('model_type')}.py"
+    if not path.is_file():
+        raise SystemExit(f"configuration {conf.get('name')!r}: no "
+                         f"architecture module {path} for its model_type")
+    name = f"bench_arch_{path.stem}"
+    mod = sys.modules.get(name)
+    if mod is None or mod.__file__ != str(path):
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod     # dataclasses look their module up here
+        spec.loader.exec_module(mod)
+    return mod
 
 
 def server_config(conf: dict, slots: int, *, telemetry=None):

@@ -2,7 +2,10 @@
 
 Counts come from shapes and the request schedule alone, never from what a
 kernel's grid happens to launch, so they read the same work whatever
-implements it.
+implements it.  The model's sizes come from its architecture module's
+record (``bench/arch/__init__.py``): ``n_heads``, ``n_kv_heads``,
+``head_dim`` and ``attn_layers`` of the layers that read the paged
+clustered kernel, and ``matmul_params`` per token.
 """
 
 from __future__ import annotations
@@ -34,14 +37,6 @@ def peaks_for(device_kind: str) -> Peaks:
                          f"{device_kind!r} (known: {sorted(PEAKS)})") from None
 
 
-def matmul_params(m) -> int:
-    """Weight-matrix parameters one token passes through: every layer's
-    q, k, v, o, gate, up and down projections and the output head."""
-    d, hq, hkv, dh = m.d_model, m.n_heads, m.n_kv_heads, m.head_dim
-    per_layer = d * hq * dh * 2 + 2 * d * hkv * dh + 3 * d * m.d_ff
-    return m.n_layers * per_layer + d * m.vocab
-
-
 def request_rows(events: np.ndarray, state_of: np.ndarray, clusters: int,
                  prompt_len: int, chunk: int) -> np.ndarray:
     """(qpos1, cov, live centroids, reads) of every position a request
@@ -69,7 +64,8 @@ def attended(rows: np.ndarray) -> np.ndarray:
 
 def paged_decode_work(rows: np.ndarray, m, kv_bytes: int = 2,
                       act_bytes: int = 2) -> tuple:
-    """(FLOPs, bytes) attention needs for ``rows`` in every layer: every
+    """(FLOPs, bytes) attention needs for ``rows`` in every layer that
+    reads the paged clustered kernel (``m.attn_layers``): every
     real row's query heads against the keys it attends; the K and V of
     those keys at the cache dtype read once per request and launch (on
     the row that ``reads``), plus each query head's q in and output out."""
@@ -78,15 +74,15 @@ def paged_decode_work(rows: np.ndarray, m, kv_bytes: int = 2,
     flops = 4.0 * m.n_heads * m.head_dim * n_keys.sum()
     kv = 2.0 * m.n_kv_heads * m.head_dim * kv_bytes * (n_keys * rows[:, 3]).sum()
     qo = 2.0 * m.n_heads * m.head_dim * act_bytes * real.sum()
-    return m.n_layers * flops, m.n_layers * (kv + qo)
+    return m.attn_layers * flops, m.attn_layers * (kv + qo)
 
 
 def step_flops(rows: np.ndarray, m) -> float:
-    """Model FLOPs of the rows: 2 x matmul parameters per real row plus the
-    attention over the positions each row attends."""
+    """Model FLOPs of the rows: 2 x ``m.matmul_params`` per real row plus
+    the attention over the positions each row attends."""
     real = int((rows[:, 0] > 0).sum())
     attn, _ = paged_decode_work(rows, m)
-    return 2.0 * matmul_params(m) * real + attn
+    return 2.0 * m.matmul_params * real + attn
 
 
 def roofline_seconds(flops: float, nbytes: float, peaks: Peaks) -> tuple:

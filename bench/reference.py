@@ -1,37 +1,35 @@
-"""Plain float32 reference of one served request under the clustered KV
-cache, written from the configuration's stated semantics.
+"""What every architecture's plain reference shares: the memory
+manager's state of one served request, the attention over it, and the
+correctness comparison's inputs and gaps.  The forward of each
+architecture is in ``bench/arch/<model_type>.py`` and builds on these.
 
 It imports nothing of the program.  Given a request's prompt and the
-tokens the engine served, it recomputes every position's logits:
+tokens the engine served, the reference recomputes every position's
+logits in float32 at ``highest`` matmul precision.  For every layer that
+holds clustered memory state, that state is rebuilt from the request's
+own schedule: the prompt streams in ``prefill_chunk`` pieces; before a
+piece whose positions would overrun the exact ring of ``kv_keep_recent``
+positions, the aged ring entries are absorbed into the centroids (dead
+centroid rows first re-seeded by farthest-point selection); after the
+prompt, coverage catches up to ``t - ring + refresh``; every
+``kv_refresh_every`` decode tokens a compaction folds the entries that
+aged past the new frontier.  Each fold is a weighted k-medians over [old
+centroids weighted by their counts ⊕ the ring entries being folded]:
+squared-L2 assignment, per-dimension weighted lower median on a
+``kmedians_bits`` fixed-point grid (power-of-two scale per dimension), at
+most ``kmedians_iters`` Lloyd rounds, values averaged per cluster.  Each
+position attends over [centroids of the state it saw, with a
++log(count) bias, ⊕ the exact keys from the coverage frontier up to
+itself].
 
-* the decoder stack (RMSNorm, q/k/v, optional per-head qk RMSNorm, RoPE,
-  SwiGLU MLP, tied or untied head) in float32 at ``highest`` matmul
-  precision;
-* the memory manager's state, layer by layer, from the request's own
-  schedule: the prompt streams in ``prefill_chunk`` pieces; before a piece
-  whose positions would overrun the exact ring of ``kv_keep_recent``
-  positions, the aged ring entries are absorbed into the centroids (dead
-  centroid rows first re-seeded by farthest-point selection); after the
-  prompt, coverage catches up to ``t - ring + refresh``; every
-  ``kv_refresh_every`` decode tokens a compaction folds the entries that
-  aged past the new frontier.  Each fold is a weighted k-medians over
-  [old centroids weighted by their counts ⊕ the ring entries being
-  folded]: squared-L2 assignment, per-dimension weighted lower median on a
-  ``kmedians_bits`` fixed-point grid (power-of-two scale per dimension),
-  at most ``kmedians_iters`` Lloyd rounds, values averaged per cluster;
-* attention of each position over [centroids of the state it saw, with a
-  +log(count) bias, ⊕ the exact keys from the coverage frontier up to
-  itself].
-
-``weight_quant`` ("int8" or "fp8", or "bf16" for a float32 configuration)
-rounds every weight matrix on the fly, per output channel: that is the
-control, one precision step below the configured one.
+``fake_quant`` with ``weight_quant`` ("int8" or "fp8", or "bf16" for a
+float32 configuration) rounds a weight matrix per output channel: that
+is the control, one precision step below the configured one.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 
 import jax
@@ -44,21 +42,6 @@ TOL = 1e-4          # Lloyd stops once no centroid coordinate moves more
 
 
 @dataclasses.dataclass(frozen=True)
-class Model:
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    eps: float
-    theta: float
-    qk_norm: bool
-    tied: bool
-
-
-@dataclasses.dataclass(frozen=True)
 class Memory:
     chunk: int
     ring: int
@@ -68,21 +51,13 @@ class Memory:
     bits: int
 
 
-def from_config(conf: dict) -> tuple:
+def memory(conf: dict) -> Memory:
+    """The memory manager's settings of a configuration file."""
     s = conf["serving"]
-    m = Model(n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-              n_heads=conf["num_attention_heads"],
-              n_kv_heads=conf["num_key_value_heads"],
-              head_dim=conf["head_dim"], d_ff=conf["intermediate_size"],
-              vocab=conf["vocab_size"],
-              eps=float(conf["rms_norm_eps"]),
-              theta=float(conf["rope_theta"]), qk_norm=conf["qk_norm"],
-              tied=conf["tie_word_embeddings"])
-    mem = Memory(chunk=s["prefill_chunk"], ring=s["kv_keep_recent"],
-                 refresh=min(s["kv_refresh_every"], s["kv_keep_recent"]),
-                 clusters=s["kv_clusters"], iters=s["kmedians_iters"],
-                 bits=s["kmedians_bits"])
-    return m, mem
+    return Memory(chunk=s["prefill_chunk"], ring=s["kv_keep_recent"],
+                  refresh=min(s["kv_refresh_every"], s["kv_keep_recent"]),
+                  clusters=s["kv_clusters"], iters=s["kmedians_iters"],
+                  bits=s["kmedians_bits"])
 
 
 # ---------------------------------------------------------------------------
@@ -246,22 +221,12 @@ def cluster_states(k, v, events, mem: Memory):
 
 
 # ---------------------------------------------------------------------------
-# the model
+# shared layers
 # ---------------------------------------------------------------------------
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-
-
-def _rope(x, pos, theta):
-    """x (L, H, D) rotated by half-split RoPE at positions pos (L,)."""
-    half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos[:, None].astype(jnp.float32) * freqs
-    sin, cos = jnp.sin(ang)[:, None, :], jnp.cos(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
 def fake_quant(w, kind, axis):
@@ -287,14 +252,15 @@ def fake_quant(w, kind, axis):
     raise ValueError(f"unknown weight_quant {kind!r}")
 
 
-def attend(q, k, v, states, state_of, pos, m: Model):
+def attend(q, k, v, states, state_of, pos):
     """q (L, Hq, D), k/v (L, Hkv, D): each position over the centroids of
-    the state it saw plus exact keys in [its frontier, itself]."""
+    the state it saw plus exact keys in [its frontier, itself], scaled by
+    1/sqrt(D).  ``states`` from ``cluster_states``."""
     kc, vc, cnt, cov = (s[state_of] for s in states)    # per position
-    L = q.shape[0]
-    g = m.n_heads // m.n_kv_heads
-    qg = q.reshape(L, m.n_kv_heads, g, m.head_dim)
-    sc = 1.0 / math.sqrt(m.head_dim)
+    L, Hq, D = q.shape
+    Hkv = k.shape[1]
+    qg = q.reshape(L, Hkv, Hq // Hkv, D)
+    sc = 1.0 / math.sqrt(D)
     s_c = jnp.einsum("lhgd,lchd->lhgc", qg, kc, precision=HI) * sc
     c = cnt.transpose(0, 2, 1)[:, :, None, :]             # (L, H, 1, C)
     s_c = jnp.where(c > 0, s_c + jnp.log(jnp.maximum(c, 1e-9)), NEG)
@@ -306,56 +272,13 @@ def attend(q, k, v, states, state_of, pos, m: Model):
     C = kc.shape[1]
     out = (jnp.einsum("lhgc,lchd->lhgd", p[..., :C], vc, precision=HI)
            + jnp.einsum("lhgm,mhd->lhgd", p[..., C:], v, precision=HI))
-    return out.reshape(L, m.n_heads * m.head_dim)
-
-
-@functools.partial(jax.jit, static_argnames=("m", "mem", "weight_quant"))
-def logits_at(params, tokens, events, state_of, out_pos, *, m: Model,
-              mem: Memory, weight_quant=None):
-    """Logits (len(out_pos), vocab) of one request.  tokens (L,) the
-    prompt then the served tokens fed back (padding past the request is
-    never attended by a real position); events/state_of from
-    ``schedule`` padded with kind-0 events."""
-    if params["prefix"] or params["tail"]:
-        raise ValueError("the reference expects one scanned layer group")
-    fq = functools.partial(fake_quant, kind=weight_quant)
-    table = params["embed"]["table"]
-    L = tokens.shape[0]
-    pos = jnp.arange(L)
-    h = fq(table[tokens], axis=-1)
-    H, Hkv, D = m.n_heads, m.n_kv_heads, m.head_dim
-
-    def layer(h, lp):
-        a, f = lp["attn"], lp["mlp"]
-        x = _rms(h, lp["norm1"]["scale"], m.eps)
-        q = jnp.matmul(x, fq(a["wq"], axis=0), precision=HI).reshape(L, H, D)
-        k = jnp.matmul(x, fq(a["wk"], axis=0), precision=HI).reshape(L, Hkv, D)
-        v = jnp.matmul(x, fq(a["wv"], axis=0), precision=HI).reshape(L, Hkv, D)
-        if m.qk_norm:
-            q = _rms(q, a["q_norm"], m.eps)
-            k = _rms(k, a["k_norm"], m.eps)
-        q, k = _rope(q, pos, m.theta), _rope(k, pos, m.theta)
-        states = cluster_states(k, v, events, mem)
-        o = attend(q, k, v, states, state_of, pos, m)
-        h = h + jnp.matmul(o, fq(a["wo"], axis=0), precision=HI)
-        x = _rms(h, lp["norm2"]["scale"], m.eps)
-        gate = jnp.matmul(x, fq(f["w_gate"], axis=0), precision=HI)
-        up = jnp.matmul(x, fq(f["w_up"], axis=0), precision=HI)
-        h = h + jnp.matmul(jax.nn.silu(gate) * up, fq(f["w_down"], axis=0),
-                           precision=HI)
-        return h, None
-
-    h, _ = jax.lax.scan(layer, h, params["scan"]["sub0"])
-    hf = _rms(h[out_pos], params["final_norm"]["scale"], m.eps)
-    if m.tied:
-        return jnp.einsum("td,vd->tv", hf, fq(table, axis=-1), precision=HI)
-    return jnp.matmul(hf, fq(params["embed"]["head"], axis=0), precision=HI)
+    return out.reshape(L, Hq * D)
 
 
 def request_inputs(prompt, served, mem: Memory, *, max_len: int,
                    max_out: int, n_events: int):
-    """Padded arrays for ``logits_at`` of one request: its prompt followed
-    by every served token but the last fed back."""
+    """Padded arrays for an architecture's ``logits_at`` of one request:
+    its prompt followed by every served token but the last fed back."""
     P, T = len(prompt), len(served)
     events, state_of = schedule(P, T, mem)
     if len(events) > n_events or P + T - 1 > max_len or T > max_out:

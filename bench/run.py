@@ -13,7 +13,8 @@ it ends with the job that crosses the mark.  Rates are taken over all jobs
 and the whole window, tails over every request and every inter-token gap.
 After the window a seeded sample of finished requests (the longest first,
 ``SAMPLE_TOKENS`` served tokens) is recomputed by the plain reference
-(``bench/reference.py``), and the widest gap between a served token's
+(the configuration's ``bench/arch/<model_type>.py`` over
+``bench/reference.py``), and the widest gap between a served token's
 reference logit and the reference's best, against the cell's limit in
 ``bench/checks/<workload>.json``, decides ``correct``.
 
@@ -146,13 +147,16 @@ def sample(jobs, seed):
     return [done[i] for i in pick]
 
 
-def reference_logits(params, prompt, served, mix, m, mem, weight_quant=None):
+def reference_logits(arch, params, prompt, served, mix, m, mem,
+                     weight_quant=None):
+    """The architecture's reference logits of one request fed its prompt
+    and served tokens, at the mix's padded sizes."""
     pmax, omax = mix["prompt_len"]["max"], mix["output_len"]["max"]
     ins = reference.request_inputs(
         prompt, served, mem, max_len=pmax + omax, max_out=omax,
         n_events=reference.max_events(pmax, omax, mem))
-    return np.asarray(reference.logits_at(params, *ins, m=m, mem=mem,
-                                          weight_quant=weight_quant))
+    return np.asarray(arch.logits_at(params, *ins, m=m, mem=mem,
+                                     weight_quant=weight_quant))
 
 
 def widest_gap(refs, judged):
@@ -192,12 +196,13 @@ def run(conf: dict, mix: dict, check: dict, *, workload: str, seed: int,
     compiles = CompileCounter()
     log(f"compile cache {enable_compile_cache()}")
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    cfg = config.model_config(conf)
-    m, mem = reference.from_config(conf)
+    arch = config.arch_for(conf)
+    cfg = arch.program_config(conf)
+    m, mem = arch.reference_model(conf), reference.memory(conf)
     vocab = conf["vocab_size"]
 
     t0 = time.perf_counter()
-    params = weights.build(cfg, seed, conf["initializer_range"])
+    params = weights.build(cfg, seed, conf["initializer_range"], arch)
     n_par, n_bytes = weights.param_count(params)
     log(f"{cfg.name}: {n_par} parameters, {n_bytes} bytes, built in "
         f"{time.perf_counter() - t0:.3f} s")
@@ -294,7 +299,7 @@ def run(conf: dict, mix: dict, check: dict, *, workload: str, seed: int,
     gc.collect()
     limit = check["max_logit_gap"]
     picked = sample(jobs, seed)
-    widest = widest_gap([reference_logits(params, p, t, mix, m, mem)
+    widest = widest_gap([reference_logits(arch, params, p, t, mix, m, mem)
                          for p, t in picked], [t for _, t in picked])
     log(f"check: {len(picked)} requests, "
         f"{sum(len(t) for _, t in picked)} served tokens recomputed")

@@ -1,13 +1,19 @@
-"""Operation and byte counts against hand counts at small shapes."""
+"""Operation and byte counts against hand counts at small shapes, and
+the counts of the benchmarked configuration pinned."""
+
+import dataclasses
 
 import numpy as np
 import pytest
 
-from bench import counts, reference
+from bench import config, counts, reference
+from bench.arch import qwen3
 
-M = reference.Model(n_layers=2, d_model=8, n_heads=4, n_kv_heads=2,
-                    head_dim=4, d_ff=16, vocab=32, eps=1e-6, theta=1e4,
-                    qk_norm=False, tied=True)
+SMALL = {"hidden_size": 8, "num_attention_heads": 4, "num_key_value_heads": 2,
+         "head_dim": 4, "intermediate_size": 16, "num_hidden_layers": 2,
+         "vocab_size": 32, "rms_norm_eps": 1e-6, "rope_theta": 1e4,
+         "qk_norm": False, "tie_word_embeddings": True}
+M = qwen3.reference_model(SMALL)
 MEM = reference.Memory(chunk=4, ring=8, refresh=2, clusters=3, iters=2,
                        bits=16)
 
@@ -15,7 +21,33 @@ MEM = reference.Memory(chunk=4, ring=8, refresh=2, clusters=3, iters=2,
 def test_matmul_params_by_hand():
     # per layer: q 8x16 + k 8x8 + v 8x8 + o 16x8 + gate/up/down 3 x 8x16
     per_layer = 128 + 64 + 64 + 128 + 3 * 128
-    assert counts.matmul_params(M) == 2 * per_layer + 8 * 32
+    assert M.matmul_params == 2 * per_layer + 8 * 32
+    assert M.attn_layers == 2
+
+
+def test_qwen3_4b_counts_are_pinned():
+    """The benchmarked configuration's counts, as the dense formula of
+    ``bench/counts.py`` gave them before the architecture modules: rows of
+    three requests' schedules (one of them folding) and padding."""
+    conf = config.load_config("qwen3-4b")
+    m = config.arch_for(conf).reference_model(conf)
+    mem = reference.memory(conf)
+    assert (m.matmul_params, m.attn_layers) == (4_022_272_000, 36)
+    rows = [counts.request_rows(*reference.schedule(p, t, mem), mem.clusters,
+                                p, mem.chunk)
+            for p, t in ((40, 30), (300, 100), (16, 16))]
+    rows = np.concatenate(rows + [np.zeros((5, 4), np.int64)])
+    assert counts.paged_decode_work(rows, m) == (27152547840.0, 2906210304.0)
+    assert counts.step_flops(rows, m) == 4041380003840.0
+
+
+def test_attention_work_counts_only_the_layers_that_read_the_kernel():
+    rows = np.array([[10, 4, 3, 1], [2, 0, 0, 1]])
+    one = dataclasses.replace(M, attn_layers=1)
+    flops, nbytes = counts.paged_decode_work(rows, M)
+    assert counts.paged_decode_work(rows, one) == (flops / 2, nbytes / 2)
+    assert counts.step_flops(rows, one) == \
+        2 * M.matmul_params * 2 + flops / 2
 
 
 def test_paged_decode_work_by_hand():
@@ -47,7 +79,7 @@ def test_kernel_count_ignores_padding_rows_and_row_order():
 def test_step_flops_by_hand():
     rows = np.array([[3, 0, 0, 1]])
     attn, _ = counts.paged_decode_work(rows, M)
-    assert counts.step_flops(rows, M) == 2 * counts.matmul_params(M) + attn
+    assert counts.step_flops(rows, M) == 2 * M.matmul_params + attn
 
 
 def test_request_rows_follow_the_schedule():

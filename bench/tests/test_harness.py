@@ -7,9 +7,10 @@ import time
 from pathlib import Path
 
 import jax
+import numpy as np
 import pytest
 
-from bench import calibrate, config, reference, weights
+from bench import calibrate, config, counts, weights
 from bench import run as harness
 
 HERE = Path(__file__).resolve().parent
@@ -27,8 +28,8 @@ def no_compile_cache(monkeypatch):
     monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "off")
 
 
-def drive(trace=False, per_layer=()):
-    return harness.run(TINY, MIX, CHECK, workload="tiny", seed=SEED,
+def drive(trace=False, per_layer=(), conf=TINY):
+    return harness.run(conf, MIX, CHECK, workload="tiny", seed=SEED,
                        seconds=0.1, trace=trace, e2e=E2E,
                        per_layer=list(per_layer), devices=jax.devices(),
                        t_start=time.perf_counter())
@@ -83,11 +84,11 @@ def test_control_fails_the_limit():
     comparison; the program is judged correct."""
     from repro.runtime.server import Server
 
-    cfg = config.model_config(TINY)
-    m, mem = reference.from_config(TINY)
+    arch = config.arch_for(TINY)
+    cfg = arch.program_config(TINY)
     srv = Server(cfg, config.server_config(TINY, MIX["slots"]),
-                 weights.build(cfg, SEED, TINY["initializer_range"]))
-    r = calibrate.readings(srv, cfg, TINY, MIX, LIMIT, SEED, m, mem)
+                 weights.build(cfg, SEED, TINY["initializer_range"], arch))
+    r = calibrate.readings(srv, arch, TINY, MIX, LIMIT, SEED)
     assert r["program_correct"] is True and r["bf16_correct"] is False
     assert r["program"] <= LIMIT < r["bf16"]
 
@@ -107,3 +108,75 @@ def test_no_chip_no_result(capsys):
     assert harness.main(["--workload", "qwen3-4b.chat_short", "--seed", "1",
                          "--seconds", "1", "--trace", "0"]) != 0
     assert capsys.readouterr().out == ""
+
+
+# an architecture brought by files alone: qwen3's forward under a made-up
+# model_type, with counts and a leaf rule of its own
+PLUGIN = """
+import dataclasses
+
+from bench.arch import qwen3
+from bench.arch.qwen3 import logits_at, program_config
+
+MATMUL_PARAMS = 123_456_789
+ATTN_LAYERS = 2
+leaf_rules = {"q_norm": "zeros"}
+
+
+def reference_model(conf):
+    return dataclasses.replace(qwen3.reference_model(conf),
+                               matmul_params=MATMUL_PARAMS,
+                               attn_layers=ATTN_LAYERS)
+"""
+
+
+def _bench_files():
+    return {p: p.read_bytes() for p in sorted(config.BENCH_DIR.rglob("*"))
+            if p.is_file() and p.suffix in (".py", ".json")}
+
+
+def test_architecture_module_is_found_by_file(tmp_path, monkeypatch):
+    """``harness.run`` takes the program's configuration, the reference,
+    the counts and the leaf rules from the module the configuration's
+    ``model_type`` names, in a directory no bench file lists."""
+    (tmp_path / "toy_hybrid.py").write_text(PLUGIN)
+    monkeypatch.setattr(config, "ARCH_DIR", tmp_path)
+    before = _bench_files()
+    built, contexts = [], []
+    real_build = weights.build
+
+    def build(*a):
+        built.append(real_build(*a))
+        return built[-1]
+
+    class Context(harness.Context):
+        def __init__(self, *a):
+            super().__init__(*a)
+            contexts.append(self)
+
+    monkeypatch.setattr(weights, "build", build)
+    monkeypatch.setattr(harness, "Context", Context)
+    conf = {**TINY, "model_type": "toy_hybrid"}
+    res = drive(trace=True, per_layer=config.load_benchmark()["per_layer"],
+                conf=conf)
+    assert res["correct"] is True
+
+    layers = built[0]["scan"]["sub0"]["attn"]
+    assert not np.asarray(layers["q_norm"]).any()           # the module's rule
+    assert (np.asarray(layers["k_norm"]) == 1).all()        # the shared rule
+    ctx, = contexts
+    assert (ctx.model.matmul_params, ctx.model.attn_layers) == (123_456_789, 2)
+    real = int((ctx.rows[:, 0] > 0).sum())
+    flops_one, _ = counts.paged_decode_work(ctx.rows, ctx.model)
+    per_layer = (4.0 * TINY["num_attention_heads"] * TINY["head_dim"]
+                 * counts.attended(ctx.rows).sum())
+    assert flops_one == 2 * per_layer
+    assert counts.step_flops(ctx.rows, ctx.model) == \
+        2.0 * 123_456_789 * real + 2 * per_layer
+    assert _bench_files() == before
+
+
+def test_unknown_model_type_exits_naming_the_path():
+    with pytest.raises(SystemExit) as e:
+        config.arch_for({**TINY, "model_type": "no_such_arch"})
+    assert str(config.ARCH_DIR / "no_such_arch.py") in str(e.value)

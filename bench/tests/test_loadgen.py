@@ -64,9 +64,10 @@ def test_warm_job_drives_every_shape_a_window_job_does(cell):
                 vocab_size=256)
     conf["serving"] = dict(conf["serving"], dtype="float32")
     mix = config.load_mix(cell["traffic"])
-    cfg = config.model_config(conf)
+    arch = config.arch_for(conf)
+    cfg = arch.program_config(conf)
     srv = Server(cfg, config.server_config(conf, mix["slots"]),
-                 weights.build(cfg, 1, 0.02))
+                 weights.build(cfg, 1, 0.02, arch))
     seen, real = set(), srv._decode_packed
 
     def packed(*a):

@@ -8,8 +8,7 @@ import pytest
 
 from bench import run as harness
 
-NEW = ("kv_compact_noop_frac", "itl_compact_gap_frac", "ttft_queue_share",
-       "setup_build_s")
+NEW = ("itl_compact_gap_frac", "ttft_queue_share", "setup_build_s")
 
 
 def job(stats, served):
@@ -20,11 +19,9 @@ def job(stats, served):
 
 
 STATS = [
-    {"kv_compact_slot_rows": 64.0, "kv_compact_slots_folded": 4.0,
-     "kv_compact_gaps": 5.0, "queue_wait_s": 1.5,
+    {"kv_compact_gaps": 5.0, "queue_wait_s": 1.5,
      "program_build_s": 2.5, "program_build_s_total": 10.0},
-    {"kv_compact_slot_rows": 32.0, "kv_compact_slots_folded": 0.0,
-     "kv_compact_gaps": 3.0, "queue_wait_s": 1.5,
+    {"kv_compact_gaps": 3.0, "queue_wait_s": 1.5,
      "program_build_s": 0.0, "program_build_s_total": 10.0},
 ]
 SERVED = [[([1, 2, 3, 4], 1000.0), ([5, 6], 2000.0)],
@@ -39,7 +36,6 @@ def test_values_by_hand():
     jobs = [job(s, v) for s, v in zip(STATS, SERVED)]
     got = {name: harness.load_reader(name)(ctx(jobs))
            for name in NEW}
-    assert got["kv_compact_noop_frac"] == pytest.approx(100 * (1 - 4 / 96))
     # tokens less one per served request: 3 + 1 + 2
     assert got["itl_compact_gap_frac"] == pytest.approx(100 * 8 / 6)
     # TTFT of the served requests: 1 + 2 + 1.5 s
@@ -55,8 +51,3 @@ def test_silent_where_the_program_counts_nothing():
         assert harness.load_reader(name)(ctx(bare)) is None, name
         assert harness.load_reader(name)(ctx([])) is None, name
 
-
-def test_no_compaction_pass_reads_nothing():
-    jobs = [job({**s, "kv_compact_slot_rows": 0.0}, v)
-            for s, v in zip(STATS, SERVED)]
-    assert harness.load_reader("kv_compact_noop_frac")(ctx(jobs)) is None

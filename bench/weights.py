@@ -7,10 +7,11 @@ each element's index and a per-leaf key, which writes the ~8 GB of a 4B
 model at memory speed instead of running a cryptographic generator.
 Every matrix, the embedding table among them, is uniform with the
 configuration's published ``initializer_range`` as its standard
-deviation; norm scales are 1.  (A table far wider than the layers'
-outputs would make every position predict its own input token, and no
-precision would ever change a greedy token.)  The reference reads these
-same arrays.
+deviation; norm scales are 1, and an architecture module's
+``leaf_rules`` may set further leaves by name to ones or zeros.  (A table
+far wider than the layers' outputs would make every position predict its
+own input token, and no precision would ever change a greedy token.)
+The reference reads these same arrays.
 """
 
 from __future__ import annotations
@@ -21,7 +22,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_ONES = frozenset({"scale", "q_norm", "k_norm"})
+# leaf name -> "ones" or "zeros"; every other leaf is drawn
+LEAF_RULES = {"scale": "ones", "q_norm": "ones", "k_norm": "ones"}
+FILLS = {"ones": jnp.ones, "zeros": jnp.zeros}
 _MASK32 = (1 << 32) - 1
 
 
@@ -62,11 +65,14 @@ def _leaf_name(path) -> str:
     return str(getattr(path[-1], "key", path[-1]))
 
 
-def build(cfg, seed: int, std: float):
+def build(cfg, seed: int, std: float, arch):
     """The serving parameter tree of ``cfg`` drawn from ``seed``, matrices
-    with standard deviation ``std``."""
+    with standard deviation ``std``; ``LEAF_RULES`` and then the
+    architecture module's ``leaf_rules``, if it has any, fill leaves by
+    name."""
     from repro.models import transformer as tfm
 
+    rules = {**LEAF_RULES, **getattr(arch, "leaf_rules", {})}
     shapes = jax.eval_shape(
         lambda: tfm.init_params_serving(jax.random.PRNGKey(0), cfg))
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
@@ -76,9 +82,9 @@ def build(cfg, seed: int, std: float):
     def make(keys):
         out = []
         for i, (path, sd) in enumerate(flat):
-            name = _leaf_name(path)
-            if name in _ONES:
-                out.append(jnp.ones(sd.shape, sd.dtype))
+            fill = rules.get(_leaf_name(path))
+            if fill is not None:
+                out.append(FILLS[fill](sd.shape, sd.dtype))
                 continue
             u = uniform_pm1(sd.shape, keys[i, 0], keys[i, 1])
             out.append((u * np.float32(std * math.sqrt(3.0))).astype(sd.dtype))
