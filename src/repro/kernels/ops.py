@@ -141,14 +141,14 @@ def clustered_decode(q, k_cents, v_cents, counts, k_tail, v_tail, t, cov,
         scale=scale, softcap=softcap, interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("scale", "softcap", "interpret"))
+@partial(jax.jit, static_argnames=("scale", "softcap", "width", "interpret"))
 def _paged_clustered_decode_jit(q, k_cents, v_cents, counts, k_pool, v_pool,
                                 row_slot, row_bt, qpos1, tw, cov, wlo, *,
                                 scale: float, softcap: float | None,
-                                interpret: bool):
+                                width: int | None, interpret: bool):
     return _pcd.paged_clustered_decode_pallas(
         q, k_cents, v_cents, counts, k_pool, v_pool, row_slot, row_bt,
-        qpos1, tw, cov, wlo, scale=scale, softcap=softcap,
+        qpos1, tw, cov, wlo, scale=scale, softcap=softcap, width=width,
         interpret=interpret)
 
 
@@ -156,6 +156,7 @@ def paged_clustered_decode(q, k_cents, v_cents, counts, k_pool, v_pool,
                            row_slot, row_bt, qpos1, tw, cov, row_wlo=None,
                            *, scale: float,
                            softcap: float | None = None,
+                           width: int | None = None,
                            interpret: bool | None = None):
     """Paged clustered-KV decode over packed ragged rows.
 
@@ -169,7 +170,9 @@ def paged_clustered_decode(q, k_cents, v_cents, counts, k_pool, v_pool,
     masked garbage block); qpos1/tw/cov per-row position + 1 / ring
     watermark / coverage frontier; ``row_wlo`` (N,) per-row retention
     window lower bound (None ⇒ zeros: frontier-only masking, the
-    bit-identical pre-policy behavior).
+    bit-identical pre-policy behavior); ``width`` (static) the longest
+    run of one slot's rows, the launch's prefill chunk (None ⇒ the row
+    count), which sets the kernel's scoring window.
 
     Under mesh serving rows, slots, and the pool shard over ``data``
     (block ids are global and rebased per shard inside the island), heads
@@ -199,8 +202,8 @@ def paged_clustered_decode(q, k_cents, v_cents, counts, k_pool, v_pool,
                 q, k_cents, v_cents, counts, k_pool, v_pool, row_slot,
                 row_bt, qpos1, tw, cov, row_wlo, mesh=r.mesh,
                 data_axes=data_axes, model_axes=model_axes, scale=scale,
-                softcap=softcap, interpret=interpret)
+                softcap=softcap, width=width, interpret=interpret)
     return _paged_clustered_decode_jit(
         q, k_cents, v_cents, counts, k_pool, v_pool, row_slot, row_bt,
-        qpos1, tw, cov, row_wlo, scale=scale, softcap=softcap,
+        qpos1, tw, cov, row_wlo, scale=scale, softcap=softcap, width=width,
         interpret=interpret)

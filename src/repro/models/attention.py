@@ -446,7 +446,7 @@ def attn_decode_clustered(p, x, cfg: ModelConfig, *, cache, t,
 def attn_decode_clustered_packed(p, x, cfg: ModelConfig, *, cache,
                                  row_slot, row_pos, row_tw, block_tables,
                                  block_size: int, kv_repeat: int = 1,
-                                 row_wlo=None):
+                                 row_wlo=None, width: int | None = None):
     """Paged clustered-KV attention over packed ragged rows.
 
     x (N, 1, d): one embedding per real (slot, position) pair this step —
@@ -457,12 +457,13 @@ def attn_decode_clustered_packed(p, x, cfg: ModelConfig, *, cache,
     contract); row_tw (N,) the row's slot ring watermark t + chunk_len
     (all of a chunk's rows are written before any row scores, so
     intra-chunk causality falls out of the per-row position mask exactly
-    as in the dense mixed launch); block_tables (B, T) global physical
-    block ids — every entry valid, with blocks being *written* this step
-    freshly allocated OR copy-on-write-owned by the engine (a sanitized
-    dead-block alias, or a block another slot still references, would
-    corrupt its true owner: kv_pool.ensure enforces ref == 1 before any
-    row's write lands).
+    as in the dense mixed launch); ``width`` the launch's static chunk
+    length, the longest run of one slot's rows; block_tables (B, T)
+    global physical block ids — every entry valid, with blocks being
+    *written* this step freshly allocated OR copy-on-write-owned by the
+    engine (a sanitized dead-block alias, or a block another slot still
+    references, would corrupt its true owner: kv_pool.ensure enforces
+    ref == 1 before any row's write lands).
 
     Prefix sharing needs no change here: a shared prefix is just several
     table rows pointing at the same physical blocks, and a slot seeded
@@ -508,7 +509,7 @@ def attn_decode_clustered_packed(p, x, cfg: ModelConfig, *, cache,
             q[:, 0], cache["k_cents"], cache["v_cents"], cache["counts"],
             k_pool, v_pool, row_slot, row_bt, qpos1, row_tw, row_cov,
             row_wlo=row_wlo, scale=_scale(cfg),
-            softcap=cfg.attn_logit_softcap)
+            softcap=cfg.attn_logit_softcap, width=width)
     # same head-gather-before-wo rule as the dense clustered path
     out_flat = annotate(out.reshape(n, 1, hq * cfg.head_dim),
                         "batch", "seq", None)

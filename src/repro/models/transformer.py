@@ -879,7 +879,7 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, t, *,
 
 def _sublayer_decode_packed(p, h, cfg: ModelConfig, cache, *, row_slot,
                             row_pos, row_tw, block_tables, block_size,
-                            kv_repeat):
+                            width, kv_repeat):
     """One 'G' sublayer over packed rows (paged clustered KV).  h
     (N, 1, d); every non-attention op is row-wise, so rows stand in for
     the batch axis exactly.  Returns (h, cache, FFN metrics)."""
@@ -887,7 +887,7 @@ def _sublayer_decode_packed(p, h, cfg: ModelConfig, cache, *, row_slot,
     y, cache = attn.attn_decode_clustered_packed(
         p["attn"], x, cfg, cache=cache, row_slot=row_slot, row_pos=row_pos,
         row_tw=row_tw, block_tables=block_tables, block_size=block_size,
-        kv_repeat=kv_repeat)
+        kv_repeat=kv_repeat, width=width)
     if cfg.post_norms:
         y = apply_norm(p["post_attn_norm"], y, cfg)
     h, metrics = _ffn(p, residual(h, y, cfg), cfg)
@@ -1045,7 +1045,7 @@ def decode_step_packed(params, cfg: ModelConfig, cache, tokens, row_slot,
         return _sublayer_decode_packed(
             p, hh, cfg, c, row_slot=row_slot, row_pos=row_pos,
             row_tw=row_tw, block_tables=block_tables,
-            block_size=block_size, kv_repeat=kv_repeat)
+            block_size=block_size, width=width, kv_repeat=kv_repeat)
 
     new_cache = {"prefix": [], "tail": []}
     for lp, c in zip(params["prefix"], cache["prefix"]):

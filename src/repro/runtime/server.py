@@ -881,6 +881,12 @@ class Server:
         # them; MoE layers: the packed program's (held, all) route counts
         rec_steps = rec_rows = 0
         moe_routes = []
+        # paged clustered attention ('G' layers): slot runs the kernel
+        # staged and scored, each a maximal stretch of packed rows of
+        # one slot (a chunk's rows, or one decode row)
+        attn_runs = 0
+        paged_attn = ("G" in cfg.layer_pattern
+                      or bool(self.params.get("prefix")))
         # KV-allocation accounting (clustered serving): live ring tokens
         # vs allocated ring capacity, so paged and dense runs report
         # comparable occupancy / fragmentation / peak-bytes numbers
@@ -1747,6 +1753,11 @@ class Server:
                     jnp.asarray(rtw),
                     jnp.asarray(rcidx), bt_dev, width)
                 moe_routes.extend(routes)       # summed after the serve
+                if paged_attn:
+                    real = rpos >= 0
+                    attn_runs += int(real[0]) + int(
+                        (real[1:] & ~(real[:-1]
+                                      & (rslot[1:] == rslot[:-1]))).sum())
                 if self._has_recurrent:
                     n_real = sum(len(rs) for rs in rows_by_shard)
                     n_later = int(((rpos >= 0) & (rcidx >= 1)).sum())
@@ -2127,6 +2138,12 @@ class Server:
         reg.counter("recurrent_rows",
                     "real rows among the launched recurrent steps"
                     ).add(rec_rows)
+        reg.counter("attn_runs",
+                    "slot runs the paged clustered-decode kernel staged "
+                    "and scored, summed over launches: maximal stretches "
+                    "of packed rows of one slot (a prompt chunk's rows, or "
+                    "one decode row); 0 without that kernel"
+                    ).add(attn_runs)
         # read back after the serve: one small copy per launch, and no
         # program whose shape follows the launch count
         routes = np.sum(jax.device_get(moe_routes), axis=0,

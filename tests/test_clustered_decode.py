@@ -10,6 +10,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro import configs
 from repro.models import attention as attn
@@ -164,56 +165,126 @@ def test_mixed_mode_masks_rows_past_chunk_len():
                                   np.asarray(out2b)[:, :2])
 
 
-def test_paged_kernel_bit_identical_to_dense():
+# packed-row layouts: a slot id stands for all of its rows (slot 0 admits
+# a chunk of L rows, the others decode one), "-" for a padding row;
+# ``width`` is the kernel's static chunk width (None: the row count)
+PACKINGS = {
+    "today": ([0, 1, 2, 3, "-", "-"], None),
+    "chunk_first": ([0, 1, 2, 3], 5),
+    "chunk_last_short": ([1, 2, 3, 0], 8),
+    "two_shards": ([1, 0, "-", 3, 2, "-"], 5),
+    "padding_between": ([0, "-", 1, "-", "-", 2, "-", 3], 5),
+    "unwrapped_after_wrapped": ([2, 3, 1, 0, "-"], 5),
+    "run_longer_than_width": ([3, 0, 2, 1], 2),
+    "width_one": ([2, 0, 1, 3, "-"], 1),
+}
+
+
+def _paged_case(packing, rng, *, t, cl, cov, bt):
+    """Dense mixed-mode operands for slots at depths ``t`` with chunk
+    lengths ``cl`` and coverage frontiers ``cov``, and the paged view of
+    them: rows packed as
+    ``packing`` says, each slot's ring read through its row of the
+    block table ``bt`` (B, T) from pools holding the same bytes."""
+    B, C, R, hq, hkv, dh = len(t), 6, 16, 4, 2, 16
+    T = bt.shape[1]
+    bs = R // T
+    L = int(cl.max())
+    k_cents = jnp.asarray(rng.normal(size=(B, C, hkv, dh)), jnp.float32)
+    v_cents = jnp.asarray(rng.normal(size=(B, C, hkv, dh)), jnp.float32)
+    counts = jnp.asarray(rng.uniform(0, 3, size=(B, C, hkv)), jnp.float32)
+    nb = int(bt.max()) + 1
+    k_pool = jnp.asarray(rng.normal(size=(nb, bs, hkv, dh)), jnp.float32)
+    v_pool = jnp.asarray(rng.normal(size=(nb, bs, hkv, dh)), jnp.float32)
+    # the dense ring of each slot is what its table row reads
+    k_tail = k_pool[bt].reshape(B, R, hkv, dh)
+    v_tail = v_pool[bt].reshape(B, R, hkv, dh)
+    cov = jnp.asarray(cov, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, L, hq, dh)), jnp.float32)
+    dense = (q, k_cents, v_cents, counts, k_tail, v_tail,
+             jnp.asarray(t, jnp.int32), cov, jnp.asarray(cl, jnp.int32))
+    rows = []
+    for s in packing:
+        rows += ([None] if s == "-" else [(s, i) for i in range(cl[s])])
+    slot = np.asarray([0 if r is None else r[0] for r in rows], np.int32)
+    pos = np.asarray([-1 if r is None else t[r[0]] + r[1] for r in rows],
+                     np.int32)
+    qp = jnp.stack([jnp.zeros((hq, dh), jnp.float32) if r is None
+                    else q[r[0], r[1]] for r in rows])
+    qpos1 = jnp.asarray(np.where(pos >= 0, pos + 1, 0), jnp.int32)
+    tw = jnp.asarray((t + cl)[slot], jnp.int32)
+    paged = (qp, k_cents, v_cents, counts, k_pool, v_pool,
+             jnp.asarray(slot), jnp.asarray(bt[slot]), qpos1, tw, cov[slot])
+    return dense, paged, rows
+
+
+@pytest.mark.parametrize("packing", list(PACKINGS))
+def test_paged_kernel_bit_identical_to_dense(packing):
     """The packed ragged paged kernel must reproduce the dense mixed-mode
     kernel BIT-exactly per (slot, position) row: same staged f32 tail
     operand, same dot_general contractions, same mask order — that is
     what makes the paged engine token-identical to the dense engine.
     Covers wrapped rings, a mid-flight chunk, decode rows at mixed
-    depths, and fully-masked padding rows."""
+    depths, and fully-masked padding rows, under every packing of slot
+    runs the kernel stages and scores: a chunk run first or last (and
+    shorter than the width), runs parted by padding, two runs reading
+    one physical block table, a short unwrapped ring after wrapped ones,
+    and runs longer than the scoring window."""
     from repro.kernels.clustered_decode import clustered_decode_pallas
     from repro.kernels.paged_clustered_decode import (
         paged_clustered_decode_pallas)
-    rng = np.random.default_rng(7)
-    B, C, R, hq, hkv, dh, L = 4, 6, 16, 4, 2, 16, 5
-    bs = 4
-    T = R // bs
-    k_cents = jnp.asarray(rng.normal(size=(B, C, hkv, dh)), jnp.float32)
-    v_cents = jnp.asarray(rng.normal(size=(B, C, hkv, dh)), jnp.float32)
-    counts = jnp.asarray(rng.uniform(0, 3, size=(B, C, hkv)), jnp.float32)
-    k_tail = jnp.asarray(rng.normal(size=(B, R, hkv, dh)), jnp.float32)
-    v_tail = jnp.asarray(rng.normal(size=(B, R, hkv, dh)), jnp.float32)
-    t = jnp.asarray([9, 3, 30, 21], jnp.int32)      # pre/post ring wrap
-    cov = jnp.asarray([6, 0, 20, 10], jnp.int32)
-    cl = jnp.asarray([L, 1, 1, 1], jnp.int32)       # slot 0 admits a chunk
-    q = jnp.asarray(rng.normal(size=(B, L, hq, dh)), jnp.float32)
+    order, width = PACKINGS[packing]
+    B, T = 4, 4
+    bt = np.arange(B * T, dtype=np.int32).reshape(B, T)
+    if packing == "two_shards":
+        bt[3] = bt[1]               # slot 3 reads slot 1's blocks
+    t = np.asarray([9, 3, 30, 21], np.int32)        # pre/post ring wrap
+    cl = np.asarray([5, 1, 1, 1], np.int32)         # slot 0 admits a chunk
+    dense_args, paged_args, rows = _paged_case(
+        order, np.random.default_rng(7), t=t, cl=cl, cov=[6, 0, 20, 10],
+        bt=bt)
+    dh = dense_args[0].shape[-1]
+    dense = clustered_decode_pallas(*dense_args, scale=dh**-0.5)
+    got = np.asarray(paged_clustered_decode_pallas(
+        *paged_args, scale=dh**-0.5, width=width))
+    assert got.shape == (len(rows),) + dense.shape[2:]
+    assert np.isfinite(got).all()
+    for ri, row in enumerate(rows):
+        if row is not None:
+            np.testing.assert_array_equal(got[ri],
+                                          np.asarray(dense)[row],
+                                          err_msg=f"row {row}")
 
-    dense = clustered_decode_pallas(q, k_cents, v_cents, counts, k_tail,
-                                    v_tail, t, cov, cl, scale=dh**-0.5)
 
-    # paged view: identity block table, pool = the same ring bytes in
-    # (nb, bs, H, Dh) blocks; pack the real rows + 2 padding rows
-    k_pool = k_tail.reshape(B * T, bs, hkv, dh)
-    v_pool = v_tail.reshape(B * T, bs, hkv, dh)
-    bt = jnp.arange(B * T, dtype=jnp.int32).reshape(B, T)
-    rows = [(b, i) for b in range(B) for i in range(int(cl[b]))]
-    n = len(rows) + 2
-    row_slot = jnp.asarray([b for b, _ in rows] + [0, 0], jnp.int32)
-    row_pos = jnp.asarray([int(t[b]) + i for b, i in rows] + [-1, -1],
-                          jnp.int32)
-    qp = jnp.concatenate([
-        jnp.stack([q[b, i] for b, i in rows]),
-        jnp.zeros((2, hq, dh), jnp.float32)])
-    qpos1 = jnp.where(row_pos >= 0, row_pos + 1, 0)
-    tw = (t + cl)[row_slot]
-    got = paged_clustered_decode_pallas(
-        qp, k_cents, v_cents, counts, k_pool, v_pool, row_slot,
-        bt[row_slot], qpos1, tw, cov[row_slot], scale=dh**-0.5)
-    assert got.shape == (n, hq, dh)
-    for ri, (b, i) in enumerate(rows):
-        np.testing.assert_array_equal(np.asarray(got)[ri],
-                                      np.asarray(dense)[b, i],
-                                      err_msg=f"row ({b},{i})")
+def test_paged_kernel_reads_no_block_past_the_watermark():
+    """A pool block that holds no position below a run's watermark
+    (min(tw, R)) is never staged: two launches whose pools differ only
+    in those blocks, finite in one and NaN in the other, give the same
+    real rows bit for bit, and every row, padding included, is
+    finite."""
+    from repro.kernels.paged_clustered_decode import (
+        paged_clustered_decode_pallas)
+    B, T = 4, 4
+    garbage = B * T                 # unmapped table entries, as served
+    t = np.asarray([2, 3, 30, 8], np.int32)
+    cl = np.asarray([5, 1, 1, 1], np.int32)
+    live = -(-np.minimum(t + cl, 16) // 4)          # (2, 1, 4, 3) blocks
+    bt = np.arange(B * T, dtype=np.int32).reshape(B, T)
+    bt = np.where(np.arange(T)[None] < live[:, None], bt, garbage)
+    _, args, rows = _paged_case([1, 0, "-", 3, 2, "-"],
+                                np.random.default_rng(3), t=t, cl=cl,
+                                cov=[0, 1, 17, 2], bt=bt.astype(np.int32))
+    dead = np.setdiff1d(np.arange(B * T + 1), bt[bt != garbage])
+    poisoned = list(args)
+    for k in (4, 5):
+        poisoned[k] = args[k].at[dead].set(jnp.nan)
+    dh = args[0].shape[-1]
+    run = lambda a: np.asarray(paged_clustered_decode_pallas(  # noqa: E731
+        *a, scale=dh**-0.5, width=5))
+    clean, dirty = run(args), run(poisoned)
+    assert np.isfinite(clean).all() and np.isfinite(dirty).all()
+    real = [ri for ri, row in enumerate(rows) if row is not None]
+    np.testing.assert_array_equal(dirty[real], clean[real])
 
 
 def test_paged_kernel_window_floor_masks_like_cov():

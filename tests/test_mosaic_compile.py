@@ -69,17 +69,21 @@ def test_clustered_decode(chip, q_shape):
     _compile(fn, chip, *_decode_shapes(q_shape))
 
 
-def test_paged_clustered_decode(chip):
+@pytest.mark.parametrize("n,width", [(32, 1), (N, L)],
+                         ids=["decode", "mixed"])
+def test_paged_clustered_decode(chip, n, width):
+    # the engine's two launch shapes: a pure-decode bucket of 32 rows, and
+    # a mixed bucket of 128 rows scoring chunks of up to 64 rows
     bf = jnp.bfloat16
     nb = B * (R // BS) + 1
     fn = functools.partial(paged_clustered_decode_pallas, scale=DH ** -0.5,
-                           interpret=False)
-    _compile(fn, chip, ((N, HQ, DH), bf), ((B, C, HKV, DH), bf),
+                           width=width, interpret=False)
+    _compile(fn, chip, ((n, HQ, DH), bf), ((B, C, HKV, DH), bf),
              ((B, C, HKV, DH), bf), ((B, C, HKV), jnp.float32),
              ((nb, BS, HKV, DH), bf), ((nb, BS, HKV, DH), bf),
-             ((N,), jnp.int32), ((N, R // BS), jnp.int32),
-             ((N,), jnp.int32), ((N,), jnp.int32), ((N,), jnp.int32),
-             ((N,), jnp.int32))
+             ((n,), jnp.int32), ((n, R // BS), jnp.int32),
+             ((n,), jnp.int32), ((n,), jnp.int32), ((n,), jnp.int32),
+             ((n,), jnp.int32))
 
 
 def test_distance_argmin(chip):

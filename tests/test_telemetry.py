@@ -502,6 +502,31 @@ class TestEngineCounters:
         ttft_s = sum(o.prefill_ms for o in outs) / 1e3
         assert st["queue_wait_s"] <= ttft_s
 
+    def test_attn_runs_count_slot_runs_not_rows(self, params):
+        """One prompt of three 8-token chunks and two one-chunk prompts
+        share three slots.  The paged kernel scores a chunk's rows as
+        one run and each decode row as its own, so over the serve the
+        runs are the chunks plus the decode rows, and each request's
+        first token comes from its last chunk row: runs = 5 chunks +
+        (18 tokens - 3 first tokens) = 20, while the rows number the
+        40 prompt tokens plus those 15 decode rows."""
+        scfg = ServerConfig(batch_size=3, max_seq=64, kv_compress=CCFG,
+                            prefill_chunk=8,
+                            paged=PagedKVConfig(block_size=4),
+                            use_clustered_batching=False)
+        rng = np.random.default_rng(1)
+        lens = {0: 24, 1: 8, 2: 8}
+        prompts = {u: rng.integers(0, 64, size=(n,)).astype(np.int32)
+                   for u, n in lens.items()}
+        srv = Server(TINY, scfg, params)
+        srv.serve([Request(u, n, 6) for u, n in lens.items()], prompts)
+        st = srv.last_stats
+        assert st["prefill_chunks"] == 5.0 and st["gen_tokens"] == 18.0
+        assert st["attn_runs"] == 20.0
+        dense = Server(TINY, dataclasses.replace(scfg, paged=None), params)
+        dense.serve([Request(u, n, 6) for u, n in lens.items()], prompts)
+        assert dense.last_stats["attn_runs"] == 0.0
+
     def test_programs_built_counts_new_programs_only(self, params):
         scfg, reqs, prompts = _two_streams(16, trace=True)
         srv = Server(TINY, scfg, params)
